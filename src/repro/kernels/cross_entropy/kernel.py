@@ -63,7 +63,7 @@ def cross_entropy_pallas(
     *,
     block_t: int = 256,
     block_v: int = 2048,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     t, v = logits.shape
     block_t = min(block_t, t)

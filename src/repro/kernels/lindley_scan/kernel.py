@@ -15,8 +15,11 @@ and one write of C — the roofline floor.  VMEM per step with tc = 256,
 bs = 128: 3 x (256, 128) fp32 ~ 384 KB.
 
 The time loop inside a chunk is a ``fori_loop`` over VMEM rows; each step
-is one max and one add on a (1, bs) tile — sequential in time, parallel
-across the 128 scenario lanes of the block.
+reads one (1, bs) row of A and S from the refs, does one max and one add,
+and stores one row of C — sequential in time, parallel across the 128
+scenario lanes of the block.  The kernel lowers for float32 only: Mosaic
+has no float64, and :mod:`repro.serving.fastsim` refuses
+``scan_impl="pallas"`` on float64 grids.
 """
 
 from __future__ import annotations
@@ -36,20 +39,15 @@ def _lindley_kernel(a_ref, s_ref, c_ref, carry_ref, *, time_chunk: int):
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    a = a_ref[...]                     # (tc, bs)
-    s = s_ref[...]
+    # Rows are read from and written to the refs at the loop index: Mosaic
+    # lowers a dynamic row of a ref, not a dynamic slice of a loaded value.
+    def step(t, comp):
+        row = pl.ds(t, 1)
+        comp = jnp.maximum(a_ref[row, :], comp) + s_ref[row, :]   # (1, bs)
+        c_ref[row, :] = comp
+        return comp
 
-    def step(t, carry):
-        comp, rows = carry
-        comp = jnp.maximum(a[t][None, :], comp) + s[t][None, :]   # (1, bs)
-        rows = jax.lax.dynamic_update_index_in_dim(rows, comp[0], t, axis=0)
-        return comp, rows
-
-    comp0 = carry_ref[...]             # (1, bs)
-    rows0 = jnp.zeros((time_chunk, a.shape[1]), a.dtype)
-    comp, rows = jax.lax.fori_loop(0, time_chunk, step, (comp0, rows0))
-    carry_ref[...] = comp
-    c_ref[...] = rows
+    carry_ref[...] = jax.lax.fori_loop(0, time_chunk, step, carry_ref[...])
 
 
 def _chained_lindley_kernel(a_ref, s_ref, c_ref, carry_ref, *,
@@ -60,28 +58,21 @@ def _chained_lindley_kernel(a_ref, s_ref, c_ref, carry_ref, *,
     def _init():
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
-    a = a_ref[...]                     # (tc, bs)
-    s = s_ref[...]                     # (J, tc, bs)
-
-    def step(t, carry):
-        comp, rows = carry             # J-tuple (1, bs), J-tuple (tc, bs)
-        arr = a[t][None, :]            # (1, bs)
-        new_comp, new_rows = [], []
+    def step(t, comp):                 # J-tuple of (1, bs) carries
+        row = pl.ds(t, 1)
+        arr = a_ref[row, :]            # (1, bs)
+        new_comp = []
         for j in range(num_stages):    # static unroll: J stays in-register
-            cj = jnp.maximum(arr, comp[j]) + s[j, t][None, :]
+            cj = jnp.maximum(arr, comp[j]) + s_ref[j, row, :]
+            c_ref[j, row, :] = cj
             new_comp.append(cj)
-            new_rows.append(jax.lax.dynamic_update_index_in_dim(
-                rows[j], cj[0], t, axis=0))
             arr = cj                   # stage j+1 consumes stage j departures
-        return tuple(new_comp), tuple(new_rows)
+        return tuple(new_comp)
 
-    carry0 = carry_ref[...]            # (J, bs)
-    comp0 = tuple(carry0[j][None, :] for j in range(num_stages))
-    rows0 = tuple(jnp.zeros((time_chunk, a.shape[1]), a.dtype)
-                  for _ in range(num_stages))
-    comp, rows = jax.lax.fori_loop(0, time_chunk, step, (comp0, rows0))
-    carry_ref[...] = jnp.concatenate(comp, axis=0)
-    c_ref[...] = jnp.stack(rows, axis=0)
+    comp0 = tuple(carry_ref[pl.ds(j, 1), :] for j in range(num_stages))
+    comp = jax.lax.fori_loop(0, time_chunk, step, comp0)
+    for j in range(num_stages):
+        carry_ref[pl.ds(j, 1), :] = comp[j]
 
 
 def lindley_scan(

@@ -2,41 +2,39 @@
 production meshes and extract roofline inputs.
 
 MUST be run as its own process (``PYTHONPATH=src python -m repro.launch.dryrun``):
-the first two statements force 512 placeholder host devices BEFORE jax
-initializes.  Do not import this module from test/bench processes that need
-the real single-device view — use a subprocess (tests/test_dryrun.py does).
+:func:`main` forces 512 placeholder host devices before jax initializes a
+backend.  Importing the module changes nothing; tests call
+:func:`lower_case` in a subprocess that forces its own device count
+(tests/test_dryrun.py).
 """
 
+import argparse
+import dataclasses
+import json
 import os
+import time
+import traceback
+from typing import Any, Dict, Optional, Tuple
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-# ---- everything below may touch jax ---------------------------------------
-
-import argparse          # noqa: E402
-import dataclasses       # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-import traceback         # noqa: E402
-from typing import Any, Dict, Optional, Tuple   # noqa: E402
-
-import jax               # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np       # noqa: E402
-
-from ..configs import INPUT_SHAPES, LONG_CONTEXT_WINDOW, InputShape  # noqa: E402
-from ..models.common import ModelConfig          # noqa: E402
-from ..models.registry import arch_ids, build_model, get_config  # noqa: E402
-from ..optim.adamw import AdamW, AdamWState      # noqa: E402
-from ..sharding.planner import ShardingPlanner, state_logical_axes  # noqa: E402
-from ..training.steps import (                   # noqa: E402
+from ..configs import INPUT_SHAPES, LONG_CONTEXT_WINDOW, InputShape
+from ..models.common import ModelConfig
+from ..models.registry import arch_ids, build_model, get_config
+from ..optim.adamw import AdamW, AdamWState
+from ..sharding.planner import ShardingPlanner, state_logical_axes
+from ..training.steps import (
     make_prefill_step,
     make_serve_step,
     make_train_step,
 )
-from .analytic import step_costs                 # noqa: E402
-from .mesh import make_production_mesh           # noqa: E402
-from .roofline import RooflineReport, analyze, model_flops_for  # noqa: E402
+from .analytic import step_costs
+from .mesh import make_production_mesh
+from .roofline import RooflineReport, analyze, model_flops_for
+
+HOST_DEVICE_FLAGS = "--xla_force_host_platform_device_count=512"
 
 BIG_ARCHS = {"llama3-405b"}     # bf16 optimizer state to fit single-pod HBM
 
@@ -139,7 +137,7 @@ def lower_case(
 
     # Trace/lower under the mesh context so interior ``shard_hint``
     # constraints (PartitionSpec-based) bind to this mesh.
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = _opt_for(cfg)
             opt_abs = jax.eval_shape(opt.init, params_abs)
@@ -245,6 +243,7 @@ def run_case(arch_id: str, shape_name: str, *, multi_pod: bool) -> Dict[str, Any
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = HOST_DEVICE_FLAGS
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

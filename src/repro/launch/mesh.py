@@ -7,8 +7,21 @@ on first jax init, and only the dry-run entrypoint forces 512 host devices.
 
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
+    """Mesh whose axes are all ``Auto``: GSPMD propagates layouts from the
+    planner's ``NamedSharding``s and the models' ``shard_hint``
+    constraints.  jax's default ``Explicit`` axes put shardings in the
+    array types and refuse such constraints."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -18,12 +31,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(*, data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over however many devices the current process has (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.

@@ -7,32 +7,28 @@ prints the largest collective instructions (bytes x trip count) so the
 dominant term can be attributed to specific tensors before choosing the next
 change.
 
-MUST run as its own process (forces 512 host devices before jax init):
+MUST run as its own process (:func:`main` forces 512 host devices before
+jax initializes a backend):
 
     PYTHONPATH=src python -m repro.launch.perf --arch minitron-4b \
         --shape train_4k --variant baseline --attribute
 """
 
+import argparse
+import dataclasses
+import json
 import os
+import re
+import time
+from typing import Dict, Optional
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import numpy as np
 
-# ---- everything below may touch jax ---------------------------------------
-
-import argparse          # noqa: E402
-import dataclasses       # noqa: E402
-import json              # noqa: E402
-import re                # noqa: E402
-import time              # noqa: E402
-from typing import Dict, Optional  # noqa: E402
-
-import numpy as np       # noqa: E402
-
-from ..configs import INPUT_SHAPES  # noqa: E402
-from ..models.common import ModelConfig  # noqa: E402
-from .dryrun import BIG_ARCHS, effective_config, lower_case  # noqa: E402
-from .analytic import step_costs  # noqa: E402
-from .roofline import (  # noqa: E402
+from ..configs import INPUT_SHAPES
+from ..models.common import ModelConfig
+from .dryrun import BIG_ARCHS, HOST_DEVICE_FLAGS, effective_config, lower_case
+from .analytic import step_costs
+from .roofline import (
     _multipliers,
     _split_computations,
     _SHAPE_RE,
@@ -155,6 +151,7 @@ def run_variant(arch: str, shape_name: str, variant: str,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = HOST_DEVICE_FLAGS
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

@@ -1,116 +1,320 @@
-"""Distributed serving launcher with Compass configuration switching.
+"""Serving launcher with Compass configuration switching.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
-        --reduced --devices 8 --mesh 2x4 --tokens 16
+    PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b
 
-Demonstrates the paper's mechanism at the MODEL level on a sharded mesh: two
-serving configurations of the same architecture (accurate = full attention /
-bf16 KV; fast = sliding-window / int8 KV) are compiled side by side against
-the SAME weights, a batch is prefLLed, and the driver decodes tokens while an
-Elastico controller switches the active executable from synthetic queue-depth
-pressure — the production-plane analogue of the paper's <10 ms pipeline
-rerouting (weights stay resident; only the compiled step changes).
+runs the full-width model on every device the process has, on a
+``1 x N`` ("data", "model") mesh (``1x1`` on one chip).  A CPU rehearsal
+of a sharded mesh forces host devices explicitly:
+
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \\
+        --reduced --host-devices 8 --mesh 2x4 --batch 4 --prompt-len 32 \\
+        --window 16
+
+Demonstrates the paper's mechanism at the MODEL level: two serving
+configurations of the same architecture (accurate = full attention / bf16
+KV; fast = sliding-window / int8 KV) are compiled side by side against the
+SAME weights, a seeded batch is prefilled, and the driver decodes tokens
+while a synthetic queue-depth schedule switches the active executable — the
+production-plane analogue of the paper's <10 ms pipeline rerouting (weights
+stay resident; only the compiled step changes).  A rung that becomes active
+first replays, through its own decode step, the tokens it missed, so every
+rung's cache and position always cover the whole sequence.
 """
 
+from __future__ import annotations
+
 import argparse
+import dataclasses
 import os
-import sys
+import time
+from typing import Dict, List, Optional, Sequence
+
+import numpy as np
+
+RUNGS = ("accurate", "fast")
+_SWITCH_DEPTH = 5     # queue depth above which the fast rung serves
 
 
-def _parse_args():
-    ap = argparse.ArgumentParser(description="sharded serving launcher")
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
+    ap = argparse.ArgumentParser(description="serving launcher")
     ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--mesh", default="2x4")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--tokens", type=int, default=16)
-    ap.add_argument("--window", type=int, default=16,
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced same-family config (CPU-sized)")
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="CPU rehearsal only: force N host devices")
+    ap.add_argument("--mesh", default="",
+                    help="data x model mesh, e.g. 2x4 (default 1 x devices)")
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--prompt-len", type=int, default=512)
+    ap.add_argument("--tokens", type=int, default=32)
+    ap.add_argument("--window", type=int, default=256,
                     help="sliding window of the fast serving config")
-    return ap.parse_args()
+    ap.add_argument("--seed", type=int, default=0)
+    return ap.parse_args(argv)
 
 
-def main() -> None:
-    args = _parse_args()
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
-        )
+def queue_depth(i: int, n_tokens: int) -> int:
+    """Synthetic queue pressure: a spike over the middle third."""
+    return 10 if n_tokens // 3 <= i < 2 * n_tokens // 3 else 0
 
-    import dataclasses
-    import time
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+def rung_schedule(n_tokens: int) -> List[str]:
+    """The rung that serves each decode step under :func:`queue_depth`."""
+    return ["fast" if queue_depth(i, n_tokens) > _SWITCH_DEPTH else "accurate"
+            for i in range(n_tokens)]
 
-    import repro.configs  # noqa: F401
-    from ..configs.reduced import reduced_config
-    from ..models.registry import build_model, get_config
-    from ..sharding.planner import ShardingPlanner
 
-    dims = [int(x) for x in args.mesh.split("x")]
-    names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    if np.prod(dims) != len(jax.devices()):
-        sys.exit(f"mesh {dims} needs {np.prod(dims)} devices")
-    mesh = jax.make_mesh(tuple(dims), names)
-
-    base = reduced_config(args.arch) if args.reduced else get_config(args.arch)
-    variants = {
+def rung_configs(base, window: int) -> Dict[str, object]:
+    """accurate = the architecture as published; fast = sliding window
+    plus int8 KV (attention-free archs keep two identical rungs so the
+    switching path still runs)."""
+    if base.family == "ssm":
+        return {"accurate": base, "fast": base}
+    return {
         "accurate": base,
         "fast": dataclasses.replace(
-            base, sliding_window=args.window,
-            kv_cache_dtype="int8" if base.family in ("dense", "hybrid") else "",
-        ),
+            base, sliding_window=window,
+            kv_cache_dtype="int8" if base.family in ("dense", "hybrid") else ""),
     }
-    if base.family == "ssm":
-        # attention-free: the fast rung varies nothing attention-shaped;
-        # keep two identical rungs to exercise the switching path.
-        variants["fast"] = base
 
-    planner = ShardingPlanner(mesh, fsdp=False, context="serve")
-    models = {k: build_model(cfg) for k, cfg in variants.items()}
-    params = models["accurate"].init(jax.random.PRNGKey(0))
-    params = jax.device_put(params, planner.param_shardings(models["accurate"]))
 
-    key = jax.random.PRNGKey(1)
-    tokens = jax.random.randint(
-        key, (args.batch, args.prompt_len), 0, base.vocab_size)
+@dataclasses.dataclass
+class ServeResult:
+    prefill_logits: np.ndarray      # (B, V) accurate rung, last prompt position
+    inputs: np.ndarray              # (n, B) token fed to each decode step
+    logits: np.ndarray              # (n, B, V) logits of each decode step
+    rungs: List[str]                # rung that served each decode step
+    switches: List[tuple]           # (step, from, to, queue depth)
+    catch_up_steps: int             # decode steps replayed at switches
+    compile_s: Dict[str, float]
+    prefill_s: float
+    decode_s: float
 
-    cache_len = args.prompt_len + args.tokens
-    with mesh:
-        states, steps = {}, {}
-        for name, m in models.items():
-            last, st = m.prefill(params, {"tokens": tokens},
-                                 cache_len=m.cache_len_for(cache_len))
-            states[name] = st
+    @property
+    def tokens(self) -> np.ndarray:
+        """(n, B) greedy tokens generated by the decode steps."""
+        return self.logits.argmax(-1).astype(np.int32)
 
-            def step(params_, st_, tok_, m_=m):
-                return m_.decode_step(params_, st_, tok_)
 
-            steps[name] = jax.jit(step)
-            print(f"compiled serving config '{name}' "
-                  f"(window={models[name].cfg.sliding_window or 'full'}, "
-                  f"kv={models[name].cfg.kv_cache_dtype or models[name].cfg.dtype})")
+def rung_programs(model, planner, *, batch: int, prompt_len: int,
+                  max_new: int) -> Dict[str, tuple]:
+    """The jitted prefill and decode step of one rung, with the planner's
+    parameter, batch and cache shardings, each paired with the abstract
+    arguments it compiles for: ``{"prefill": (jitted, args), "decode":
+    (jitted, args)}``.  Call under ``jax.set_mesh(planner.mesh)``."""
+    import jax
+    import jax.numpy as jnp
 
-        active = "accurate"
-        tok = jnp.argmax(last, -1).astype(jnp.int32)
+    from ..sharding.planner import state_logical_axes
+
+    param_sh = planner.param_shardings(model)
+    params_abs = model.abstract_params()
+    tok_abs = jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)
+    step_abs = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    cache_len = model.cache_len_for(prompt_len + max_new)
+
+    def prefill(p, t):
+        return model.prefill(p, {"tokens": t}, cache_len=cache_len)
+
+    state_abs = jax.eval_shape(prefill, params_abs, tok_abs)[1]
+    state_sh = planner.tree_shardings(state_abs,
+                                      state_logical_axes(state_abs))
+    return {
+        "prefill": (jax.jit(prefill,
+                            in_shardings=(param_sh,
+                                          planner.batch_spec(tok_abs.shape)),
+                            out_shardings=(None, state_sh)),
+                    (params_abs, tok_abs)),
+        "decode": (jax.jit(model.decode_step,
+                           in_shardings=(param_sh, state_sh,
+                                         planner.batch_spec(step_abs.shape)),
+                           out_shardings=(None, state_sh),
+                           donate_argnums=(1,)),
+                   (params_abs, state_abs, step_abs)),
+    }
+
+
+class ServingPlane:
+    """Two serving rungs over one set of weights on one mesh.
+
+    Parameters are generated on the devices from ``seed`` (nothing is
+    loaded) with the planner's shardings; each rung has a compiled prefill
+    and a compiled decode step (:func:`rung_programs`) whose cache
+    shardings come from the same planner.  ``compile_s`` holds the seconds
+    spent compiling each."""
+
+    def __init__(self, cfg, *, mesh, window: int, batch: int,
+                 prompt_len: int, max_new: int, seed: int = 0):
+        import jax
+        import jax.numpy as jnp
+
+        from ..models.registry import build_model
+        from ..sharding.planner import ShardingPlanner
+
+        self.cfg, self.mesh, self.seed = cfg, mesh, seed
+        self.models = {k: build_model(c)
+                       for k, c in rung_configs(cfg, window).items()}
+        acc = self.models["accurate"]
+        planner = ShardingPlanner(mesh, fsdp=False, context="serve")
+        self.param_sh = planner.param_shardings(acc)
+        self.compile_s: Dict[str, float] = {}
+        self.tokens_sh = planner.batch_spec((batch, prompt_len))
+        self.step_tok_sh = planner.batch_spec((batch,))
+        key_abs = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                       sharding=planner.replicated())
+
+        with jax.set_mesh(mesh):
+            init = self._compile(
+                "init", jax.jit(acc.init, out_shardings=self.param_sh),
+                (key_abs,))
+            self.prefill_fns, self.step_fns = {}, {}
+            for name, m in self.models.items():
+                progs = rung_programs(m, planner, batch=batch,
+                                      prompt_len=prompt_len, max_new=max_new)
+                self.prefill_fns[name] = self._compile(
+                    f"prefill_{name}", *progs["prefill"])
+                self.step_fns[name] = self._compile(
+                    f"decode_{name}", *progs["decode"])
+        self.params = init(jax.device_put(jax.random.PRNGKey(seed),
+                                          planner.replicated()))
+
+    def _compile(self, name, jitted, abstract_args):
         t0 = time.perf_counter()
-        for i in range(args.tokens):
-            # synthetic queue pressure: spike in the middle third
-            depth = 10 if args.tokens // 3 <= i < 2 * args.tokens // 3 else 0
-            want = "fast" if depth > 5 else "accurate"
+        compiled = jitted.lower(*abstract_args).compile()
+        self.compile_s[name] = time.perf_counter() - t0
+        return compiled
+
+    def prompt(self, batch: int, prompt_len: int):
+        """The seeded prompt batch, placed with the batch sharding."""
+        import jax
+
+        tokens = jax.random.randint(jax.random.PRNGKey(self.seed + 1),
+                                    (batch, prompt_len), 0,
+                                    self.cfg.vocab_size)
+        return jax.device_put(tokens, self.tokens_sh)
+
+    def generate(self, tokens, n_tokens: int) -> ServeResult:
+        """Prefill every rung on ``tokens``, then greedily decode
+        ``n_tokens`` steps under :func:`rung_schedule`."""
+        import jax
+        import jax.numpy as jnp
+
+        schedule = rung_schedule(n_tokens)
+        t0 = time.perf_counter()
+        states, first = {}, None
+        for name in RUNGS:
+            last, states[name] = self.prefill_fns[name](self.params, tokens)
+            if name == "accurate":
+                first = last
+        jax.block_until_ready(states)
+        prefill_s = time.perf_counter() - t0
+
+        def greedy(logits):
+            return jax.device_put(jnp.argmax(logits, -1).astype(jnp.int32),
+                                  self.step_tok_sh)
+
+        fed: List = []                   # decode inputs, in order
+        done = {name: 0 for name in RUNGS}
+        logits_steps, switches = [], []
+        catch_up = 0
+        tok = greedy(first)
+        active = schedule[0]
+        t0 = time.perf_counter()
+        for i, want in enumerate(schedule):
             if want != active:
-                print(f"  token {i:3d}: switch {active} -> {want} "
-                      f"(queue depth {depth})")
+                switches.append((i, active, want, queue_depth(i, n_tokens)))
                 active = want
-            logits, states[active] = steps[active](params, states[active], tok)
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            # replay the tokens this rung missed while the other one served
+            for past in fed[done[active]:]:
+                _, states[active] = self.step_fns[active](
+                    self.params, states[active], past)
+                catch_up += 1
+            fed.append(tok)
+            logits, states[active] = self.step_fns[active](
+                self.params, states[active], tok)
+            done[active] = len(fed)
+            logits_steps.append(logits)
+            tok = greedy(logits)
         jax.block_until_ready(tok)
-        dt = time.perf_counter() - t0
-    print(f"decoded {args.tokens} tokens x batch {args.batch} in {dt:.2f}s "
-          f"({dt / args.tokens * 1e3:.0f} ms/token on CPU)")
+        decode_s = time.perf_counter() - t0
+        return ServeResult(
+            prefill_logits=np.asarray(first, np.float32),
+            inputs=np.stack([np.asarray(t) for t in fed]),
+            logits=np.stack([np.asarray(x, np.float32)
+                             for x in logits_steps]),
+            rungs=schedule, switches=switches, catch_up_steps=catch_up,
+            compile_s=dict(self.compile_s), prefill_s=prefill_s,
+            decode_s=decode_s)
+
+
+def device_info() -> Dict[str, object]:
+    import jax
+
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def build_mesh(spec: str = "", devices=None):
+    """("data", "model") mesh over ``devices`` (default: all of them);
+    ``spec`` "DxM" fixes its shape, else it is 1 x len(devices)."""
+    import jax
+
+    from .mesh import make_mesh
+
+    devices = list(jax.devices() if devices is None else devices)
+    dims = ([int(x) for x in spec.split("x")] if spec
+            else [1, len(devices)])
+    if len(dims) != 2 or dims[0] * dims[1] != len(devices):
+        raise SystemExit(f"mesh {spec or dims} does not cover the "
+                         f"{len(devices)} devices")
+    return make_mesh(dims, ("data", "model"), devices=devices)
+
+
+def run(args: argparse.Namespace):
+    """Build the serving plane that ``args`` describes, serve the seeded
+    prompt batch, print what happened; returns (plane, result)."""
+    import repro.configs  # noqa: F401
+    from ..configs.reduced import reduced_config
+    from ..models.registry import get_config
+    from .cache import use_compile_cache
+
+    use_compile_cache()
+    cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+    mesh = build_mesh(args.mesh)
+    dev = device_info()
+    print(f"devices: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']} mesh={dict(mesh.shape)}")
+    print(f"{cfg.arch_id}{' (reduced)' if args.reduced else ''}: "
+          f"{cfg.num_layers} layers, d_model {cfg.d_model}, "
+          f"vocab {cfg.vocab_size}; batch {args.batch}, "
+          f"prompt {args.prompt_len}, {args.tokens} new tokens")
+    plane = ServingPlane(cfg, mesh=mesh, window=args.window,
+                         batch=args.batch, prompt_len=args.prompt_len,
+                         max_new=args.tokens, seed=args.seed)
+    for name, m in plane.models.items():
+        print(f"compiled serving config '{name}' "
+              f"(window={m.cfg.sliding_window or 'full'}, "
+              f"kv={m.cfg.kv_cache_dtype or m.cfg.dtype}): prefill "
+              f"{plane.compile_s[f'prefill_{name}']:.1f}s, decode "
+              f"{plane.compile_s[f'decode_{name}']:.1f}s")
+    res = plane.generate(plane.prompt(args.batch, args.prompt_len),
+                         args.tokens)
+    for i, src, dst, depth in res.switches:
+        print(f"  token {i:3d}: switch {src} -> {dst} (queue depth {depth})")
+    print(f"prefill {args.batch} x {args.prompt_len} tokens in "
+          f"{res.prefill_s:.3f}s; decoded {args.tokens} tokens x batch "
+          f"{args.batch} ({res.catch_up_steps} catch-up steps) in "
+          f"{res.decode_s:.3f}s on {dev['count']} {dev['kind']}")
+    return plane, res
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+    if args.host_devices:
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.host_devices}")
+    run(args)
 
 
 if __name__ == "__main__":

@@ -1,14 +1,15 @@
 """Distributed training launcher.
 
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
-        --reduced --steps 20 --devices 8 --mesh 2x4
+        --reduced --steps 20
 
-Builds the mesh (forcing host devices when requested — must happen before jax
-initializes), plans GSPMD shardings for params / optimizer / batches through
-the same ShardingPlanner the production dry-run uses, and runs REAL sharded
-train steps on synthetic data with loss/step-time logging and checkpointing.
-On a TPU pod this same entry point runs with ``--devices 0`` (use the real
-device set) and ``--mesh 16x16``.
+Builds a ("data", "model") mesh over the process's devices (``1 x N`` unless
+``--mesh`` says otherwise), plans GSPMD shardings for params / optimizer /
+batches through the same ShardingPlanner the production dry-run uses, and
+runs REAL sharded train steps on synthetic data with loss/step-time logging
+and checkpointing.  A CPU rehearsal of a sharded mesh forces host devices
+explicitly (``--host-devices 8 --mesh 2x4``); on a TPU pod the same entry
+point runs with ``--mesh 16x16``.
 """
 
 import argparse
@@ -25,11 +26,11 @@ def _parse_args():
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--devices", type=int, default=8,
-                    help="force N host devices (0 = use the real device set)")
-    ap.add_argument("--mesh", default="2x4",
+    ap.add_argument("--host-devices", type=int, default=0,
+                    help="CPU rehearsal only: force N host devices")
+    ap.add_argument("--mesh", default="",
                     help="mesh shape, e.g. 2x4 (data x model) or 2x4x4 "
-                         "(pod x data x model)")
+                         "(pod x data x model); default 1 x devices")
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--log-every", type=int, default=5)
     return ap.parse_args()
@@ -37,9 +38,9 @@ def _parse_args():
 
 def main() -> None:
     args = _parse_args()
-    if args.devices:
+    if args.host_devices:
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
+            f"--xla_force_host_platform_device_count={args.host_devices}"
         )
 
     # jax may only be imported after the device-count flag is set
@@ -57,15 +58,19 @@ def main() -> None:
     from ..sharding.planner import ShardingPlanner
     from ..training.steps import make_train_step
     from ..checkpoint.io import save_checkpoint
+    from .cache import use_compile_cache
+    from .mesh import make_mesh
 
-    dims = [int(x) for x in args.mesh.split("x")]
+    use_compile_cache()
+    dims = ([int(x) for x in args.mesh.split("x")] if args.mesh
+            else [1, len(jax.devices())])
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
     if len(dims) not in (2, 3):
         sys.exit("mesh must be 2- or 3-dimensional")
     if np.prod(dims) != len(jax.devices()):
         sys.exit(f"mesh {dims} needs {np.prod(dims)} devices, "
                  f"have {len(jax.devices())}")
-    mesh = jax.make_mesh(tuple(dims), names)
+    mesh = make_mesh(dims, names)
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     model = build_model(cfg)
@@ -87,7 +92,7 @@ def main() -> None:
     sample = next(lm.batches())
     batch_sh = {k: planner.batch_spec(v.shape) for k, v in sample.items()}
 
-    with mesh:
+    with jax.set_mesh(mesh):
         step_fn = jax.jit(
             make_train_step(model, opt),
             in_shardings=(param_sh, None, batch_sh),

@@ -1342,7 +1342,6 @@ def _sweep_pipeline_jax(dag: WorkflowDAG, topo: Sequence[int], sink: int,
     Padded arrival slots carry ``+inf`` so they stay trailing through
     every device-side sort and join."""
     from . import fastsim as _fs
-    from jax.experimental import enable_x64
 
     from .fastsim import _fingerprint, lognormal_params
 
@@ -1398,7 +1397,7 @@ def _sweep_pipeline_jax(dag: WorkflowDAG, topo: Sequence[int], sink: int,
     # One strided pass to the scan layout (J, N, B); per-stage slices are
     # then contiguous device pushes inside the grid evaluator.
     S_nb = np.ascontiguousarray(S.transpose(0, 2, 1))
-    with enable_x64():
+    with _fs._jax.enable_x64(True):
         sink_comp = _fs._jax_pipeline_grid(
             A, S_nb, topo_meta, impl,
             out_pos=(sink_pos,))[sink_pos]             # (B, N_max)

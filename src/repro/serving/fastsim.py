@@ -66,16 +66,22 @@ backends (``backend="numpy" | "jax" | "auto"``):
   associative form on accelerators, where its log-depth parallelism
   wins); the c > 1 Kiefer-Wolfowitz recursion as a ``lax.scan`` whose
   carry is the sorted length-c workload vector, maintained by an unrolled
-  insertion (comparator) network.  ``scan_impl="pallas"`` routes the
-  c = 1 scan through the blocked Pallas kernel
-  (:func:`repro.kernels.lindley_scan.lindley_scan`, CPU-interpreter
-  fallback like ssm_scan).  Arrival and service draws always come from
-  the *same* content-keyed numpy streams as the numpy backend, so the jax
-  grids are held to tight allclose parity (bit-exact schedules for the
-  sequential impl) — only the recursion and reductions move to the
-  accelerator.  The scan math runs in float64 via the scoped
-  ``jax.experimental.enable_x64`` context, which does not leak x64 into
-  the rest of the process.
+  insertion (comparator) network.  ``scan_impl="pallas"`` names the
+  blocked Pallas kernel
+  (:func:`repro.kernels.lindley_scan.lindley_scan`), which lowers for
+  float32 only: the float64 grids here refuse it with a ValueError.
+  Arrival and service draws always come from the *same* content-keyed
+  numpy streams as the numpy backend, so the jax grids are held to tight
+  allclose parity (bit-exact schedules for the sequential impl on the
+  CPU) — only the recursion and reductions move to the accelerator.  The
+  scan math runs in float64 via the scoped ``jax.enable_x64(True)``
+  context, which does not leak x64 into the rest of the process.  A TPU
+  has no native float64 and XLA emulates it there, so on the chip the
+  grids are close to numpy, not bit-equal: on one TPU v5e the largest
+  relative differences were 6e-13 on p95s and compliance equal or within
+  2e-15 (``Planner.validate``, ``dag.sweep_pipeline``), and up to 1e-7
+  on a streamed stage's mean wait (``traces.replay_dag``);
+  ``chip_smoke.py`` holds them to 1e-6.
 - **auto** — jax when it is importable, the pool fits the jax path
   (c <= ``_JAX_MAX_SERVERS``), and the padded grid is big enough to
   amortize dispatch (>= ``_JAX_AUTO_MIN_SLOTS`` request slots); numpy
@@ -765,6 +771,31 @@ def _resolve_scan_impl(scan_impl: str) -> str:
 if _jax is not None:
     import functools as _functools
 
+    def _pallas_completions(At, St):
+        """Completion times from the blocked Pallas Lindley kernel: an
+        (N, B) service grid goes through ``lindley_scan``, a (J, N, B)
+        stack through ``chained_lindley_scan``; both are padded to block
+        multiples with self-masking zero slots.  The kernel lowers for
+        float32 only, and the fast-path grids are float64, so this
+        refuses them instead of failing in the TPU lowering."""
+        if At.dtype == _jnp.float64:
+            raise ValueError(
+                "scan_impl='pallas' runs the float32 Lindley kernel, which "
+                "cannot take the float64 fast-path grids; use "
+                "'sequential' or 'associative'")
+        from ..kernels.lindley_scan import chained_lindley_scan, lindley_scan
+
+        n, b = At.shape
+        tc, bb = 256, 128
+        pn, pb = (-n) % tc, (-b) % bb
+        Ap = _jnp.pad(At, ((0, pn), (0, pb)))
+        if St.ndim == 2:
+            Sp = _jnp.pad(St, ((0, pn), (0, pb)))
+            return lindley_scan(Ap, Sp, block_b=bb, time_chunk=tc)[:n, :b]
+        Sp = _jnp.pad(St, ((0, 0), (0, pn), (0, pb)))
+        return chained_lindley_scan(
+            Ap, Sp, block_b=bb, time_chunk=tc)[:, :n, :b]
+
     def _jax_c1(At, St, impl: str):
         """(waits, lats) of the c = 1 Lindley system; inputs (N, B)."""
         if impl == "sequential":
@@ -782,15 +813,8 @@ if _jax is not None:
             from ..kernels.lindley_scan import lindley_scan_ref
 
             C = lindley_scan_ref(At, St)
-        else:  # pallas: blocked kernel, padded to block multiples
-            from ..kernels.lindley_scan import lindley_scan as _lk
-
-            n, b = At.shape
-            tc, bb = 256, 128
-            pn, pb = (-n) % tc, (-b) % bb
-            Ap = _jnp.pad(At, ((0, pn), (0, pb)))
-            Sp = _jnp.pad(St, ((0, pn), (0, pb)))
-            C = _lk(Ap, Sp, block_b=bb, time_chunk=tc)[:n, :b]
+        else:
+            C = _pallas_completions(At, St)
         return C - St - At, C - At
 
     def _jax_kw(At, St, c: int):
@@ -890,14 +914,7 @@ if _jax is not None:
             from ..kernels.lindley_scan import lindley_scan_ref
 
             return lindley_scan_ref(At, St)
-        from ..kernels.lindley_scan import lindley_scan as _lk
-
-        n, b = At.shape
-        tc, bb = 256, 128
-        pn, pb = (-n) % tc, (-b) % bb
-        Ap = _jnp.pad(At, ((0, pn), (0, pb)))
-        Sp = _jnp.pad(St, ((0, pn), (0, pb)))
-        return _lk(Ap, Sp, block_b=bb, time_chunk=tc)[:n, :b]
+        return _pallas_completions(At, St)
 
     @_functools.partial(_jax.jit, static_argnames=("impl",))
     def _jax_chained_fused(At, St, *, impl: str):
@@ -910,14 +927,7 @@ if _jax is not None:
             from ..kernels.lindley_scan import chained_lindley_scan_ref
 
             return chained_lindley_scan_ref(At, St)
-        from ..kernels.lindley_scan import chained_lindley_scan as _clk
-
-        j, n, b = St.shape
-        tc, bb = 256, 128
-        pn, pb = (-n) % tc, (-b) % bb
-        Ap = _jnp.pad(At, ((0, pn), (0, pb)))
-        Sp = _jnp.pad(St, ((0, 0), (0, pn), (0, pb)))
-        return _clk(Ap, Sp, block_b=bb, time_chunk=tc)[:, :n, :b]
+        return _pallas_completions(At, St)
 
     def _jax_pipeline_grid(A, S, topo_meta, impl: str, out_pos=None):
         """Per-stage completions of a batched workflow DAG: device scans,
@@ -1059,12 +1069,10 @@ if _jax is not None:
         stage run stage-by-stage with a host re-sort between stages,
         because Kiefer-Wolfowitz completions are not monotone in
         dispatch order."""
-        from jax.experimental import enable_x64
-
         impl = _resolve_scan_impl(scan_impl)
         n = A.size
         out = np.empty((len(stage_S), n), dtype=float)
-        with enable_x64():
+        with _jax.enable_x64(True):
             if all(c == 1 for c in servers):
                 order = np.argsort(A, kind="stable")
                 At = _jnp.asarray(A[order][:, None])
@@ -1113,10 +1121,8 @@ def _sweep_jax(A: np.ndarray, S: np.ndarray, cell_counts: np.ndarray,
     """Host wrapper for the jax backend: scoped x64, device reductions,
     host p95.  Inputs are the same (B, N_max) grids the numpy backend
     consumes — the draws are shared, only the evaluation engine differs."""
-    from jax.experimental import enable_x64
-
     impl = _resolve_scan_impl(scan_impl)
-    with enable_x64():
+    with _jax.enable_x64(True):
         mean_wait, mean_lat, compliance, lats = _jax_sweep(
             _jnp.asarray(A), _jnp.asarray(S), _jnp.asarray(cell_counts),
             _jnp.asarray(float(slo_s) if slo_s is not None else 0.0),

@@ -471,11 +471,18 @@ def _make_chunk_dag_jax(num_stages: int):
     closed form (``P = cumsum(S)``, ``M = cummax(A - (P - S))``,
     ``C = P + max(M, comp0)``) with stage j+1 consuming stage j's
     completions in-register.  Allclose (~1e-13) vs the numpy chunk, not
-    bit-exact: XLA's ``cumsum`` may reassociate the prefix additions —
-    the same caveat :func:`_chunk_closed_form` already carries vs the
-    sequential recursion.  Returns per-stage waits, sojourns, departures
-    and the carried backlog tails."""
+    bit-exact: the log-depth prefix scan reassociates the prefix
+    additions — the same caveat :func:`_chunk_closed_form` already carries
+    vs the sequential recursion.  Returns per-stage waits, sojourns,
+    departures and the carried backlog tails.
+
+    The prefixes are explicit ``associative_scan``s, which is what
+    ``cumsum`` / ``cummax`` lower to everywhere but the TPU.  There they
+    lower to a ``reduce_window`` whose float64 compile takes minutes even
+    at 4096 slots (3 minutes at 4096, 6 at 16384, for a described v5e),
+    against seconds for the scan at 262144."""
     _jax, _jnp = _fs._jax, _fs._jnp
+    scan = _jax.lax.associative_scan
 
     @_jax.jit
     def chunk(A, S, comp0):           # (n,), (J, n), (J,)
@@ -483,8 +490,8 @@ def _make_chunk_dag_jax(num_stages: int):
         waits, lats, tails = [], [], []
         for j in range(num_stages):   # static unroll over stages
             s = S[j]
-            P = _jnp.cumsum(s)
-            M = _jax.lax.cummax(cur - (P - s))
+            P = scan(_jnp.add, s)
+            M = scan(_jnp.maximum, cur - (P - s))
             C = P + _jnp.maximum(M, comp0[j])
             waits.append(_jnp.maximum(C - s - cur, 0.0))
             lats.append(C - cur)
@@ -549,7 +556,6 @@ def replay_mix(trace, service_mean_s: Sequence[float],
     sketches = [StreamingQuantile(quantile_bins, init_hi) for _ in range(K)]
 
     if engine == "jax":
-        from jax.experimental import enable_x64
         scan_chunk = _make_chunk_jax()
         F = np.zeros((c, K), dtype=float)
     else:
@@ -577,7 +583,7 @@ def replay_mix(trace, service_mean_s: Sequence[float],
             pad = max(4096, 1 << (n - 1).bit_length()) - n
             Ap = np.pad(A, (0, pad))
             Sp = np.pad(S, ((0, pad), (0, 0)))
-            with enable_x64():
+            with _fs._jax.enable_x64(True):
                 w, l, Fj = scan_chunk(_fs._jnp.asarray(Ap),
                                       _fs._jnp.asarray(Sp),
                                       _fs._jnp.asarray(F))
@@ -713,7 +719,6 @@ def replay_dag(trace, stage_mean_s: Sequence[float],
 
     use_jax = backend == "jax"
     if use_jax:
-        from jax.experimental import enable_x64
         chunk_jax = _make_chunk_dag_jax(J)
 
     for A in trace.chunks():
@@ -733,7 +738,7 @@ def replay_dag(trace, stage_mean_s: Sequence[float],
             pad = max(4096, 1 << (n - 1).bit_length()) - n
             Ap = np.pad(A, (0, pad))
             Sp = np.pad(S, ((0, 0), (0, pad)))
-            with enable_x64():
+            with _fs._jax.enable_x64(True):
                 wj, lj, dep, tails = chunk_jax(
                     _fs._jnp.asarray(Ap), _fs._jnp.asarray(Sp),
                     _fs._jnp.asarray(comp0))

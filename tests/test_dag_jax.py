@@ -123,19 +123,20 @@ def test_chained_jax_sequential_bit_exact_vs_numpy(n_stages, max_c, seed):
 
 
 @needs_jax
-@given(st.sampled_from(["associative", "pallas"]),
-       st.integers(1, 4), st.integers(0, 10**6))
+@given(st.integers(1, 4), st.integers(0, 10**6))
 @settings(max_examples=12, deadline=None)
-def test_chained_jax_reassociated_impls_allclose(scan_impl, n_stages, seed):
-    """The associative max-plus scan and the blocked Pallas kernel are
-    float reorderings of the same recursion: allclose against numpy and
-    the oracle on random all-c = 1 chains, never judged bit-exact."""
+def test_chained_jax_reassociated_impls_allclose(n_stages, seed):
+    """The associative max-plus scan is a float reordering of the same
+    recursion: allclose against numpy and the oracle on random all-c = 1
+    chains, never judged bit-exact.  (The float32 Pallas kernel is
+    refused on these float64 chains; tests/test_kernels.py holds it to
+    its oracle at float32.)"""
     A, services, _ = _draw_chain(seed, n_stages=n_stages, max_c=1)
     servers = [1] * n_stages
     ref = chained_lindley(A, services, num_servers=servers,
                           backend="numpy")
     got = chained_lindley(A, services, num_servers=servers,
-                          backend="jax", scan_impl=scan_impl)
+                          backend="jax", scan_impl="associative")
     np.testing.assert_allclose(ref, got, rtol=1e-9, atol=1e-12)
     np.testing.assert_allclose(got, _heap_tandem(A, services, servers),
                                rtol=1e-9, atol=1e-12)

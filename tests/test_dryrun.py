@@ -40,8 +40,9 @@ def test_reduced_dryrun_all_kinds_on_8_devices():
         from repro.configs import INPUT_SHAPES
         from repro.configs.reduced import reduced_config
         from repro.launch.dryrun import lower_case
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         out = {}
         cases = [
             ("internlm2-1.8b", "train_4k"),
@@ -118,7 +119,7 @@ def test_train_launcher_subprocess():
     env["PYTHONPATH"] = SRC
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "internlm2-1.8b",
-         "--reduced", "--steps", "4", "--devices", "8", "--mesh", "2x4",
+         "--reduced", "--steps", "4", "--host-devices", "8", "--mesh", "2x4",
          "--log-every", "2"],
         capture_output=True, text=True, timeout=900, env=env,
     )
@@ -133,7 +134,8 @@ def test_serve_launcher_subprocess():
     env["PYTHONPATH"] = SRC
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "internlm2-1.8b",
-         "--devices", "8", "--mesh", "2x4", "--tokens", "9"],
+         "--reduced", "--host-devices", "8", "--mesh", "2x4", "--batch", "4",
+         "--prompt-len", "32", "--window", "16", "--tokens", "9"],
         capture_output=True, text=True, timeout=900, env=env,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]

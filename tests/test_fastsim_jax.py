@@ -81,12 +81,12 @@ def test_maxplus_combine_is_associative():
     associative scan at all.  Mathematically exact; in floats the shift
     components compose as a1 + a2 + a3 in either grouping, so parity is
     last-ulp allclose, not bit equality."""
-    import jax.experimental
+    import jax
 
     from repro.kernels.lindley_scan import maxplus_combine
 
     rng = np.random.default_rng(0)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for _ in range(50):
             a1, a2, a3 = rng.normal(scale=3.0, size=(3, 8))
             b1, b2, b3 = rng.normal(scale=3.0, size=(3, 8))
@@ -105,14 +105,14 @@ def test_maxplus_identity_element():
     """(0, -inf) is the identity: padding slots carry it, which is why the
     sweep can right-pad ragged traces without changing any cell.  Adding
     zero and maxing with -inf are exact, so this one IS bit equality."""
-    import jax.experimental
+    import jax
 
     from repro.kernels.lindley_scan import maxplus_combine
 
     rng = np.random.default_rng(1)
     a, b = rng.normal(size=4), rng.normal(size=4)
     ident = (np.zeros(4), np.full(4, -np.inf))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         for out in (maxplus_combine(ident, (a, b)),
                     maxplus_combine((a, b), ident)):
             np.testing.assert_array_equal(np.asarray(out[0]), a)
@@ -141,12 +141,34 @@ def test_jax_c1_sequential_bit_exact_order_stats(seed, lognormal):
 @needs_jax
 @pytest.mark.parametrize("scan_impl", ["associative", "pallas"])
 def test_jax_c1_reassociated_scans_tight_parity(scan_impl):
-    """Max-plus reassociation (associative_scan / blocked Pallas kernel)
-    computes the same recursion in a different grouping: tight allclose,
-    including on the order statistics."""
+    """Max-plus reassociation (associative_scan) computes the same
+    recursion in a different grouping: tight allclose, including on the
+    order statistics.  The blocked Pallas kernel is the other
+    reassociation, but it lowers for float32 only: on these float64 grids
+    it is refused with a ValueError that names the working impls, instead
+    of failing inside the TPU lowering (tests/test_kernels.py holds the
+    kernel to its oracle at float32)."""
     ref = _sweep(backend="numpy", seed=5)
+    if scan_impl == "pallas":
+        with pytest.raises(ValueError, match="float32 Lindley kernel"):
+            _sweep(backend="jax", scan_impl=scan_impl, seed=5)
+        return
     got = _sweep(backend="jax", scan_impl=scan_impl, seed=5)
     _assert_parity(ref, got, rtol=1e-9)
+
+
+@needs_jax
+@pytest.mark.parametrize("servers", [[1, 1], [1, 2]])
+def test_pallas_scan_impl_refuses_float64(servers):
+    """The chained engines refuse the float32 Pallas kernel on float64
+    grids too: all-c = 1 chains (fused kernel) and pooled chains (per-stage
+    kernel between Kiefer-Wolfowitz stages)."""
+    rng = np.random.default_rng(0)
+    A = np.sort(rng.uniform(0.0, 10.0, 64))
+    services = [rng.exponential(0.1, 64) for _ in range(2)]
+    with pytest.raises(ValueError, match="float32 Lindley kernel"):
+        fastsim.chained_lindley(A, services, num_servers=servers,
+                                backend="jax", scan_impl="pallas")
 
 
 @needs_jax

@@ -184,3 +184,39 @@ def test_cross_entropy_rejects_nondivisible():
     labels = jnp.zeros((100,), jnp.int32)
     with pytest.raises(ValueError):
         ce_ops.cross_entropy(logits, labels, block_t=64, block_v=512, interpret=True)
+
+
+# -- Lindley scan ------------------------------------------------------------------
+
+from repro.kernels.lindley_scan import ops as ls_ops, ref as ls_ref
+
+
+def _lindley_inputs(n, b, j=None, seed=0):
+    rng = np.random.default_rng(seed)
+    arrivals = np.sort(rng.uniform(0.0, n * 0.1, (n, b)), axis=0)
+    shape = (n, b) if j is None else (j, n, b)
+    services = rng.exponential(0.1, shape)
+    return (jnp.asarray(arrivals, jnp.float32),
+            jnp.asarray(services, jnp.float32))
+
+
+@pytest.mark.parametrize("n,b,tc,bb", [
+    (256, 128, 256, 128),    # one block
+    (512, 256, 128, 128),    # carry across time chunks and lane blocks
+])
+def test_lindley_scan_matches_ref(n, b, tc, bb):
+    a, s = _lindley_inputs(n, b)
+    out = ls_ops.lindley_scan(a, s, block_b=bb, time_chunk=tc, interpret=True)
+    ref = ls_ref.lindley_scan_ref(a, s)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("j", [1, 3])
+def test_chained_lindley_scan_matches_ref(j):
+    a, s = _lindley_inputs(512, 256, j=j, seed=j)
+    out = ls_ops.chained_lindley_scan(a, s, block_b=128, time_chunk=128,
+                                      interpret=True)
+    ref = ls_ref.chained_lindley_scan_ref(a, s)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
