@@ -14,15 +14,20 @@ One chip, four phases:
   internlm2-1.8b at full width, batch 8, prompt 512, 32 new tokens,
   switching accurate -> fast -> accurate — checked for width, shapes,
   finite logits and both switches.  Then the same launcher code at
-  float32 activations and the highest matmul precision, whose accurate
-  prefill and decode logits (including the steps after the switch back,
-  which replay the tokens the fast rung served) are held to the model's
-  own teacher-forced ``forward`` over the same tokens with the repo's
-  decode-parity criteria (as in ``tests/test_kv_int8.py``): max |diff| <=
-  5% of max |logit| and correlation >= 0.999.  The bf16 launcher is not
-  held to them: this random-init model's near-flat logits move by tens of
-  percent under bf16 rounding alone, which another program shape
-  reorders; its distance from float32 is printed.
+  float32 activations and the highest matmul precision, each rung's
+  logits held to its own attention pattern's teacher-forced float32
+  ``forward`` over the same tokens (``serve.rung_agreement``).  The
+  accurate rung (prefill, and the steps after the switch back, which
+  replay the tokens the fast rung served) meets the repo's decode-parity
+  criteria (as in ``tests/test_kv_int8.py``): max |diff| <= 5% of max
+  |logit| and correlation >= 0.999.  The fast rung (sliding window 256,
+  int8 KV) is held to the window forward with full-precision KV: int8
+  rounding moves this random-init model's logits by tens of percent, so
+  the bound is a mean correlation >= 0.85 over its steps (0.90 measured
+  on a v5e), and it must sit closer to its window forward than to full
+  attention (0.07 measured).  Last, the bf16 launcher's rungs against
+  the float32 forward at 1, 8 and 24 layers, a witness of the bf16 noise
+  floor; at one layer each rung's mean correlation must reach 0.9.
 - **validate**, **sweep_pipeline**, **replay_dag**: the jax Planner
   sweeps, each with ``backend="jax"`` against ``backend="numpy"`` on the
   same draws: ``Planner.validate`` on the RAG ladder of
@@ -53,9 +58,12 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 
 ARCH = "internlm2-1.8b"
-LOGIT_REL_TOL = 0.05     # max |diff| / max |logit|, bf16 decode parity
+LOGIT_REL_TOL = 0.05        # max |diff| / max |logit|, float32 decode parity
 LOGIT_MIN_CORR = 0.999
-SIM_REL_TOL = 1e-6       # mean latency / wait, exact p95
+INT8_MIN_MEAN_CORR = 0.85   # fast rung (int8 KV) vs its window forward
+WITNESS_DEPTHS = (1, 8)     # cut depths of the bf16-vs-float32 witness
+BF16_DEPTH1_MIN_MEAN_CORR = 0.9
+SIM_REL_TOL = 1e-6          # mean latency / wait, exact p95
 SIM_COMPLIANCE_TOL = 1e-3
 
 
@@ -66,16 +74,6 @@ def log(msg: str) -> None:
 def check(cond: bool, msg: str) -> None:
     if not cond:
         raise RuntimeError(msg)
-
-
-def logit_agreement(ref, got):
-    """(max |diff| / max |ref|, correlation) of two logit arrays."""
-    import numpy as np
-
-    ref = np.asarray(ref, np.float64).ravel()
-    got = np.asarray(got, np.float64).ravel()
-    rel = float(np.abs(ref - got).max() / max(np.abs(ref).max(), 1e-30))
-    return rel, float(np.corrcoef(ref, got)[0, 1])
 
 
 def grid_diff(name, ref, got, *, rel_tol=None, abs_tol=None):
@@ -143,10 +141,11 @@ def reference_plane(cfg, args, devices):
     """The launcher's serving plane at float32 activations under the
     highest matmul precision, on ``devices``: (plane, result).
 
-    The bf16 rungs of this random-init 24-layer model sit near a noise
-    floor: bf16 rounding alone, reordered by another program shape,
-    moves its near-flat logits by tens of percent.  Comparisons that are
-    to mean anything run the same code at float32."""
+    The bf16 rungs of this random-init model sit near a noise floor: its
+    stacked layers are initialized with unit-variance weights, so each
+    layer adds a residual update some 1e5 times the token embedding, and
+    bf16 rounding of that update swamps the token signal.  Comparisons
+    that are to mean anything run the same code at float32."""
     import dataclasses
 
     import jax
@@ -158,7 +157,7 @@ def reference_plane(cfg, args, devices):
             dataclasses.replace(cfg, dtype="float32"),
             mesh=serve.build_mesh(devices=devices), window=args.window,
             batch=args.batch, prompt_len=args.prompt_len,
-            max_new=args.tokens, seed=args.seed)
+            max_new=args.tokens)
         res = plane.generate(plane.prompt(args.batch, args.prompt_len),
                              args.tokens)
     for k, v in res.compile_s.items():
@@ -166,44 +165,72 @@ def reference_plane(cfg, args, devices):
     return plane, res
 
 
+def report_agreement(label, agree) -> None:
+    for rung, by_ref in agree.items():
+        for ref_rung, (rel, mn, mean) in by_ref.items():
+            log(f"  {label} {rung} rung vs {ref_rung} forward: "
+                f"max_rel={rel!r} min_corr={mn!r} mean_corr={mean!r}")
+
+
 def phase_serve() -> None:
+    import dataclasses
+
     import jax
-    import jax.numpy as jnp
     import numpy as np
+
+    from repro.launch import serve
 
     args, plane, res = serve_launcher()
     cfg = plane.cfg
+    prompt = np.asarray(plane.prompt(args.batch, args.prompt_len))
+    forwards = serve.reference_forwards(cfg, args.window)
+    with jax.default_matmul_precision("highest"):
+        witness = {cfg.num_layers: serve.rung_agreement(
+            forwards, plane.params, prompt, res)}
     del plane
     gc.collect()
 
-    # the same launcher code at float32, held to the accurate model's
-    # teacher-forced forward over the prompt and every decode input, on
-    # two rows of the batch
+    # the same launcher code at float32: each rung held to its own
+    # attention pattern's teacher-forced forward over the prompt and every
+    # decode input (the accurate rung's steps after the switch back
+    # included), on two rows of the batch
     ref_plane, ref = reference_plane(cfg, args, jax.devices()[:1])
-    rows, p = 2, args.prompt_len
-    prompt = np.asarray(ref_plane.prompt(args.batch, p))[:rows]
-    seq = np.concatenate([prompt, ref.inputs[:, :rows].T], axis=1)
-    t0 = time.perf_counter()
-    with jax.default_matmul_precision("highest"):
-        fwd = jax.jit(ref_plane.models["accurate"].forward)
-        teacher = np.asarray(
-            fwd(ref_plane.params, {"tokens": jnp.asarray(seq)})[0], np.float32)
-    log(f"  float32 teacher-forced forward {seq.shape}: "
-        f"{time.perf_counter() - t0:.3f} s (compile included)")
-    worst_rel, worst_corr = logit_agreement(teacher[:, p - 1],
-                                            ref.prefill_logits[:rows])
-    for i, rung in enumerate(ref.rungs):
-        if rung == "accurate":
-            rel, corr = logit_agreement(teacher[:, p + i], ref.logits[i, :rows])
-            worst_rel, worst_corr = max(worst_rel, rel), min(worst_corr, corr)
-    log(f"  float32 accurate prefill + decode vs forward: max_rel={worst_rel!r} "
-        f"min_corr={worst_corr!r}")
     check(ref.switches == res.switches, "float32 run switched differently")
-    check(worst_rel <= LOGIT_REL_TOL and worst_corr >= LOGIT_MIN_CORR,
+    with jax.default_matmul_precision("highest"):
+        agree = serve.rung_agreement(forwards, ref_plane.params, prompt, ref)
+    del ref_plane
+    gc.collect()
+    report_agreement("float32", agree)
+    rel, mn, _ = agree["accurate"]["accurate"]
+    check(rel <= LOGIT_REL_TOL and mn >= LOGIT_MIN_CORR,
           "accurate decode disagrees with the forward reference")
-    rel, corr = logit_agreement(ref.prefill_logits, res.prefill_logits)
-    log(f"  bf16 launcher prefill vs float32 (noise floor, not checked): "
-        f"max_rel={rel!r} corr={corr!r}")
+    own, full = agree["fast"]["fast"], agree["fast"]["accurate"]
+    check(own[2] >= INT8_MIN_MEAN_CORR and own[2] > full[2],
+          "fast (window, int8 KV) decode disagrees with its window forward")
+
+    # witness for the bf16 noise floor: the launcher's bf16 rungs against
+    # the float32 forward, by depth
+    for depth in WITNESS_DEPTHS:
+        cut = dataclasses.replace(cfg, num_layers=depth)
+        plane = serve.ServingPlane(
+            cut, mesh=serve.build_mesh(devices=jax.devices()[:1]),
+            window=args.window, batch=args.batch,
+            prompt_len=args.prompt_len, max_new=args.tokens)
+        res_d = plane.generate(plane.prompt(args.batch, args.prompt_len),
+                               args.tokens)
+        with jax.default_matmul_precision("highest"):
+            witness[depth] = serve.rung_agreement(
+                serve.reference_forwards(cut, args.window), plane.params,
+                prompt, res_d)
+        del plane
+        gc.collect()
+    for depth in sorted(witness):
+        report_agreement(f"bf16 {depth} layers",
+                         {r: {r: witness[depth][r][r]} for r in serve.RUNGS})
+    for rung in serve.RUNGS:
+        mean = witness[1][rung][rung][2]
+        check(mean >= BF16_DEPTH1_MIN_MEAN_CORR,
+              f"bf16 {rung} rung at one layer: mean correlation {mean!r}")
 
 
 def param_bytes_by_device(params):
@@ -219,6 +246,8 @@ def param_bytes_by_device(params):
 def phase_serve_sharded() -> None:
     import jax
     import numpy as np
+
+    from repro.launch.serve import logit_agreement
 
     devices = jax.devices()
     check(len(devices) == 4, f"--chips 4 needs 4 devices, found {len(devices)}")

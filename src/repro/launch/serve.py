@@ -33,6 +33,7 @@ import numpy as np
 
 RUNGS = ("accurate", "fast")
 _SWITCH_DEPTH = 5     # queue depth above which the fast rung serves
+SEED = 0              # parameters from PRNGKey(SEED), prompt from SEED + 1
 
 
 def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
@@ -49,7 +50,6 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--window", type=int, default=256,
                     help="sliding window of the fast serving config")
-    ap.add_argument("--seed", type=int, default=0)
     return ap.parse_args(argv)
 
 
@@ -137,21 +137,21 @@ def rung_programs(model, planner, *, batch: int, prompt_len: int,
 class ServingPlane:
     """Two serving rungs over one set of weights on one mesh.
 
-    Parameters are generated on the devices from ``seed`` (nothing is
+    Parameters are generated on the devices from ``SEED`` (nothing is
     loaded) with the planner's shardings; each rung has a compiled prefill
     and a compiled decode step (:func:`rung_programs`) whose cache
     shardings come from the same planner.  ``compile_s`` holds the seconds
     spent compiling each."""
 
     def __init__(self, cfg, *, mesh, window: int, batch: int,
-                 prompt_len: int, max_new: int, seed: int = 0):
+                 prompt_len: int, max_new: int):
         import jax
         import jax.numpy as jnp
 
         from ..models.registry import build_model
         from ..sharding.planner import ShardingPlanner
 
-        self.cfg, self.mesh, self.seed = cfg, mesh, seed
+        self.cfg, self.mesh = cfg, mesh
         self.models = {k: build_model(c)
                        for k, c in rung_configs(cfg, window).items()}
         acc = self.models["accurate"]
@@ -175,7 +175,7 @@ class ServingPlane:
                     f"prefill_{name}", *progs["prefill"])
                 self.step_fns[name] = self._compile(
                     f"decode_{name}", *progs["decode"])
-        self.params = init(jax.device_put(jax.random.PRNGKey(seed),
+        self.params = init(jax.device_put(jax.random.PRNGKey(SEED),
                                           planner.replicated()))
 
     def _compile(self, name, jitted, abstract_args):
@@ -188,7 +188,7 @@ class ServingPlane:
         """The seeded prompt batch, placed with the batch sharding."""
         import jax
 
-        tokens = jax.random.randint(jax.random.PRNGKey(self.seed + 1),
+        tokens = jax.random.randint(jax.random.PRNGKey(SEED + 1),
                                     (batch, prompt_len), 0,
                                     self.cfg.vocab_size)
         return jax.device_put(tokens, self.tokens_sh)
@@ -247,6 +247,60 @@ class ServingPlane:
             decode_s=decode_s)
 
 
+def reference_forwards(cfg, window: int) -> Dict[str, object]:
+    """Each rung's teacher-forced reference: its attention pattern's
+    ``forward`` at float32 with full-precision KV, jitted as
+    ``fn(params, tokens) -> (B, S, V) logits``.  Parameters are float32 in
+    every rung, so one set serves all.  Call under the highest matmul
+    precision for a float32 reference on a TPU."""
+    import jax
+
+    from ..models.registry import build_model
+
+    out = {}
+    for name, c in rung_configs(dataclasses.replace(cfg, dtype="float32"),
+                                window).items():
+        m = build_model(dataclasses.replace(c, kv_cache_dtype=""))
+        out[name] = jax.jit(lambda p, t, m=m: m.forward(p, {"tokens": t})[0])
+    return out
+
+
+def logit_agreement(ref, got) -> tuple:
+    """(max |diff| / max |ref|, correlation) of two logit arrays."""
+    ref = np.asarray(ref, np.float64).ravel()
+    got = np.asarray(got, np.float64).ravel()
+    rel = float(np.abs(ref - got).max() / max(np.abs(ref).max(), 1e-30))
+    return rel, float(np.corrcoef(ref, got)[0, 1])
+
+
+def rung_agreement(forwards, params, prompt, res: ServeResult,
+                   rows: int = 2) -> Dict[str, Dict[str, tuple]]:
+    """Hold the logits of ``res`` to the teacher-forced ``forwards`` (see
+    :func:`reference_forwards`) over ``prompt`` and every decode input, on
+    the first ``rows`` rows.  Decode step i sits at position P + i of the
+    forward, the accurate prefill at P - 1.  Returns ``{served rung:
+    {reference rung: (max_rel, min_corr, mean_corr)}}`` over the steps
+    that rung served (the accurate rung's include its prefill)."""
+    p = prompt.shape[1]
+    seq = np.concatenate([np.asarray(prompt)[:rows],
+                          res.inputs[:, :rows].T], axis=1)
+    refs = {name: np.asarray(fn(params, seq), np.float32)
+            for name, fn in forwards.items()}
+    out: Dict[str, Dict[str, tuple]] = {}
+    for rung in RUNGS:
+        pairs = [(p + i, res.logits[i, :rows])
+                 for i, r in enumerate(res.rungs) if r == rung]
+        if rung == "accurate":
+            pairs.insert(0, (p - 1, res.prefill_logits[:rows]))
+        out[rung] = {}
+        for ref_rung, ref in refs.items():
+            agree = [logit_agreement(ref[:, pos], got) for pos, got in pairs]
+            out[rung][ref_rung] = (max(a for a, _ in agree),
+                                   min(c for _, c in agree),
+                                   float(np.mean([c for _, c in agree])))
+    return out
+
+
 def device_info() -> Dict[str, object]:
     import jax
 
@@ -291,7 +345,7 @@ def run(args: argparse.Namespace):
           f"prompt {args.prompt_len}, {args.tokens} new tokens")
     plane = ServingPlane(cfg, mesh=mesh, window=args.window,
                          batch=args.batch, prompt_len=args.prompt_len,
-                         max_new=args.tokens, seed=args.seed)
+                         max_new=args.tokens)
     for name, m in plane.models.items():
         print(f"compiled serving config '{name}' "
               f"(window={m.cfg.sliding_window or 'full'}, "

@@ -1189,8 +1189,8 @@ def sweep_pipeline(dag: WorkflowDAG,
     ``"auto"`` picks jax only for grids whose ``stages x slots`` product
     clears :func:`repro.serving.fastsim.resolve_backend`'s amortization
     bar.  With ``scan_impl="sequential"`` (the CPU ``"auto"``
-    resolution) the jax grids are bit-exact against numpy; associative /
-    pallas impls are float64-allclose."""
+    resolution) the jax grids are bit-exact against numpy; the
+    associative impl is float64-allclose."""
     from .fastsim import (
         _fingerprint,
         chained_lindley,

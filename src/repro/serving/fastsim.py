@@ -486,9 +486,7 @@ def chained_lindley(
     On jax, all-c = 1 chains run as *one* fused multi-stage recursion:
     ``scan_impl="sequential"`` replays the numpy closed form's exact op
     order per (request, stage) and is bit-exact; ``"associative"``
-    (J chained max-plus scans) and ``"pallas"`` (the blocked multi-stage
-    :func:`repro.kernels.lindley_scan.chained_lindley_scan` kernel) are
-    held to float64 allclose.  Chains containing c > 1 stages keep those
+    (J chained max-plus scans) is held to float64 allclose.  Chains containing c > 1 stages keep those
     stages on the carried comparator-chain scan (bit-exact), with host
     re-sorts between stages.
 
@@ -763,6 +761,11 @@ def _resolve_scan_impl(scan_impl: str) -> str:
     if scan_impl not in _SCAN_IMPLS:
         raise ValueError(f"unknown scan_impl {scan_impl!r} "
                          f"(expected one of {_SCAN_IMPLS})")
+    if scan_impl == "pallas":
+        raise ValueError(
+            "scan_impl='pallas' runs the float32 Lindley kernel, which "
+            "cannot take the float64 fast-path grids; use "
+            "'sequential' or 'associative'")
     if scan_impl != "auto":
         return scan_impl
     return "sequential" if _jax.default_backend() == "cpu" else "associative"
@@ -770,31 +773,6 @@ def _resolve_scan_impl(scan_impl: str) -> str:
 
 if _jax is not None:
     import functools as _functools
-
-    def _pallas_completions(At, St):
-        """Completion times from the blocked Pallas Lindley kernel: an
-        (N, B) service grid goes through ``lindley_scan``, a (J, N, B)
-        stack through ``chained_lindley_scan``; both are padded to block
-        multiples with self-masking zero slots.  The kernel lowers for
-        float32 only, and the fast-path grids are float64, so this
-        refuses them instead of failing in the TPU lowering."""
-        if At.dtype == _jnp.float64:
-            raise ValueError(
-                "scan_impl='pallas' runs the float32 Lindley kernel, which "
-                "cannot take the float64 fast-path grids; use "
-                "'sequential' or 'associative'")
-        from ..kernels.lindley_scan import chained_lindley_scan, lindley_scan
-
-        n, b = At.shape
-        tc, bb = 256, 128
-        pn, pb = (-n) % tc, (-b) % bb
-        Ap = _jnp.pad(At, ((0, pn), (0, pb)))
-        if St.ndim == 2:
-            Sp = _jnp.pad(St, ((0, pn), (0, pb)))
-            return lindley_scan(Ap, Sp, block_b=bb, time_chunk=tc)[:n, :b]
-        Sp = _jnp.pad(St, ((0, 0), (0, pn), (0, pb)))
-        return chained_lindley_scan(
-            Ap, Sp, block_b=bb, time_chunk=tc)[:, :n, :b]
 
     def _jax_c1(At, St, impl: str):
         """(waits, lats) of the c = 1 Lindley system; inputs (N, B)."""
@@ -809,12 +787,9 @@ if _jax is not None:
             comp0 = _jnp.zeros(At.shape[1], At.dtype)
             _, (waits, lats) = _jax.lax.scan(step, comp0, (At, St))
             return waits, lats
-        if impl == "associative":
-            from ..kernels.lindley_scan import lindley_scan_ref
+        from ..kernels.lindley_scan import lindley_scan_ref
 
-            C = lindley_scan_ref(At, St)
-        else:
-            C = _pallas_completions(At, St)
+        C = lindley_scan_ref(At, St)
         return C - St - At, C - At
 
     def _jax_kw(At, St, c: int):
@@ -910,24 +885,20 @@ if _jax is not None:
         """Completion times (N, B) of one c = 1 stage, by scan impl."""
         if impl == "sequential":
             return _jax_chained_seq(At, St[None])[0]
-        if impl == "associative":
-            from ..kernels.lindley_scan import lindley_scan_ref
+        from ..kernels.lindley_scan import lindley_scan_ref
 
-            return lindley_scan_ref(At, St)
-        return _pallas_completions(At, St)
+        return lindley_scan_ref(At, St)
 
     @_functools.partial(_jax.jit, static_argnames=("impl",))
     def _jax_chained_fused(At, St, *, impl: str):
         """All-c = 1 tandem, fused per impl: one multi-stage sequential
-        scan (bit-exact), J chained max-plus associative scans, or the
-        blocked multi-stage Pallas kernel (both allclose)."""
+        scan (bit-exact) or J chained max-plus associative scans
+        (allclose)."""
         if impl == "sequential":
             return _jax_chained_seq(At, St)
-        if impl == "associative":
-            from ..kernels.lindley_scan import chained_lindley_scan_ref
+        from ..kernels.lindley_scan import chained_lindley_scan_ref
 
-            return chained_lindley_scan_ref(At, St)
-        return _pallas_completions(At, St)
+        return chained_lindley_scan_ref(At, St)
 
     def _jax_pipeline_grid(A, S, topo_meta, impl: str, out_pos=None):
         """Per-stage completions of a batched workflow DAG: device scans,
@@ -1180,10 +1151,11 @@ def simulate_batch(
     scan_impl: c = 1 time-scan choice for the jax backend — ``"auto"``
         (sequential on CPU, associative on accelerators),
         ``"sequential"`` (``lax.scan``, bit-exact vs numpy),
-        ``"associative"`` (max-plus ``lax.associative_scan``), or
-        ``"pallas"`` (``repro.kernels.lindley_scan`` blocked TPU kernel;
-        interpreter mode on CPU).  Ignored for c > 1, which always uses
-        the comparator-insertion ``lax.scan``, and by the numpy backend.
+        or ``"associative"`` (max-plus ``lax.associative_scan``);
+        ``"pallas"`` (the float32 ``repro.kernels.lindley_scan`` kernel)
+        is refused on these float64 grids.  Ignored for c > 1, which
+        always uses the comparator-insertion ``lax.scan``, and by the
+        numpy backend.
 
     Determinism: cell (r, k, l) depends only on ``seed``, the replication
     index r, and its coordinates' *inputs* (rate or trace content, config

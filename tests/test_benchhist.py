@@ -395,15 +395,16 @@ def test_detector_validates_its_knobs():
 
 
 @settings(max_examples=40)
-@given(st.lists(st.floats(min_value=-0.2, max_value=0.2), min_size=2,
+@given(st.lists(st.floats(min_value=-0.15, max_value=0.15), min_size=2,
                 max_size=12),
        st.floats(min_value=10.0, max_value=1e6))
 def test_prop_bounded_noise_on_flat_trajectory_never_fires(noise, base):
-    """Relative noise within ±20% of a flat baseline stays inside the 30%
-    default tolerance of the window median, whatever the window contents."""
+    """Relative noise within ±15% of a flat baseline stays inside the 30%
+    default tolerance of the window median, whatever the window contents
+    (at ±20% it need not: 0.8b against a median of 1.2b is 0.67x)."""
     runs = [make_run(base * (1.0 + n), timestamp=ts(i % 24))
             for i, n in enumerate(noise)]
-    # median of history in [0.8b, 1.2b]; current >= 0.8b >= 0.7 * median
+    # median of history in [0.85b, 1.15b]; current >= 0.85b > 0.7 * 1.15b
     assert detect_regressions(runs) == []
 
 
