@@ -105,10 +105,10 @@ def timed(fn, *args, **kw):
 # --------------------------------------------------------------------------
 
 
-def report_serve(res) -> None:
+def report_serve(plane, res) -> None:
     import numpy as np
 
-    for k, v in res.compile_s.items():
+    for k, v in plane.compile_s.items():
         log(f"  compile {k}: {v:.3f} s")
     log(f"  prefill {res.prefill_s:.3f} s; decode {len(res.rungs)} tokens "
         f"({res.catch_up_steps} catch-up steps) {res.decode_s:.3f} s")
@@ -133,7 +133,7 @@ def serve_launcher(extra=()):
     check(width == (24, 2048, 92544), f"(layers, d_model, vocab) = {width}")
     check(res.logits.shape == (args.tokens, args.batch, cfg.vocab_size),
           f"logits shape {res.logits.shape}")
-    report_serve(res)
+    report_serve(plane, res)
     return args, plane, res
 
 
@@ -160,7 +160,7 @@ def reference_plane(cfg, args, devices):
             max_new=args.tokens)
         res = plane.generate(plane.prompt(args.batch, args.prompt_len),
                              args.tokens)
-    for k, v in res.compile_s.items():
+    for k, v in plane.compile_s.items():
         log(f"  compile float32 {k}: {v:.3f} s")
     return plane, res
 

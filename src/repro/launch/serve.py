@@ -25,11 +25,14 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import os
 import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+
+from .spans import Recorder, Span, seconds
 
 RUNGS = ("accurate", "fast")
 _SWITCH_DEPTH = 5     # queue depth above which the fast rung serves
@@ -84,11 +87,37 @@ class ServeResult:
     inputs: np.ndarray              # (n, B) token fed to each decode step
     logits: np.ndarray              # (n, B, V) logits of each decode step
     rungs: List[str]                # rung that served each decode step
-    switches: List[tuple]           # (step, from, to, queue depth)
-    catch_up_steps: int             # decode steps replayed at switches
-    compile_s: Dict[str, float]
-    prefill_s: float
-    decode_s: float
+    spans: List[Span]               # the call's spans (see ``generate``)
+    counters: Dict[str, int]        # host_bytes, catch_up_steps
+
+    def _first(self, name: str) -> Span:
+        return next(s for s in self.spans if s.name == name)
+
+    @property
+    def prefill_s(self) -> float:
+        """Both rungs' prefill: from the first dispatch until the device
+        has done them."""
+        return seconds(self._first("serve.prefill"),
+                       self._first("serve.prefill.wait"))
+
+    @property
+    def decode_s(self) -> float:
+        """Every decode step: from the first dispatch until the device has
+        done them."""
+        return seconds(self._first("serve.decode"),
+                       self._first("serve.decode.wait"))
+
+    @property
+    def switches(self) -> List[tuple]:
+        """(step, from, to, queue depth) of each rung switch."""
+        return [(s.attrs["step"], s.attrs["frm"], s.attrs["to"],
+                 s.attrs["depth"]) for s in self.spans
+                if s.name == "serve.switch"]
+
+    @property
+    def catch_up_steps(self) -> int:
+        """Decode steps replayed at switches."""
+        return self.counters["catch_up_steps"]
 
     @property
     def tokens(self) -> np.ndarray:
@@ -158,6 +187,7 @@ class ServingPlane:
         planner = ShardingPlanner(mesh, fsdp=False, context="serve")
         self.param_sh = planner.param_shardings(acc)
         self.compile_s: Dict[str, float] = {}
+        self._calls = itertools.count()       # call ids of ``generate``
         self.tokens_sh = planner.batch_spec((batch, prompt_len))
         self.step_tok_sh = planner.batch_spec((batch,))
         key_abs = jax.ShapeDtypeStruct((2,), jnp.uint32,
@@ -195,56 +225,96 @@ class ServingPlane:
 
     def generate(self, tokens, n_tokens: int) -> ServeResult:
         """Prefill every rung on ``tokens``, then greedily decode
-        ``n_tokens`` steps under :func:`rung_schedule`."""
+        ``n_tokens`` steps under :func:`rung_schedule`, then hand the
+        logits to the host.
+
+        The call's spans, all under one ``serve.call`` with the call's id:
+        ``serve.prefill`` (``rung``) around each rung's prefill dispatch
+        and ``serve.prefill.wait`` until the device has done them;
+        ``serve.decode`` (``rung``, ``step``, ``pos``) around each served
+        step, from its dispatch through the greedy pick; ``serve.switch``
+        (``step``, ``frm``, ``to``, ``depth``, ``replayed``) around a
+        switch and the ``serve.catchup`` steps (``rung``, ``pos``) that
+        replay the tokens the new rung missed; ``serve.decode.wait`` until
+        the device has done them; ``serve.handoff`` from there to the
+        result, with a ``serve.handoff.transfer`` (``bytes``) around each
+        array copied to the host and ``serve.handoff.stack`` around their
+        float32 conversion and stacking.  Counters: ``host_bytes`` copied to the host,
+        ``catch_up_steps``."""
         import jax
         import jax.numpy as jnp
 
-        schedule = rung_schedule(n_tokens)
-        t0 = time.perf_counter()
-        states, first = {}, None
-        for name in RUNGS:
-            last, states[name] = self.prefill_fns[name](self.params, tokens)
-            if name == "accurate":
-                first = last
-        jax.block_until_ready(states)
-        prefill_s = time.perf_counter() - t0
+        rec = Recorder(next(self._calls))
+        with rec.span("serve.call", call=rec.call):
+            schedule = rung_schedule(n_tokens)
+            p = tokens.shape[1]
+            states, first = {}, None
+            for name in RUNGS:
+                with rec.span("serve.prefill", rung=name):
+                    last, states[name] = self.prefill_fns[name](self.params,
+                                                                tokens)
+                if name == "accurate":
+                    first = last
+            with rec.span("serve.prefill.wait"):
+                jax.block_until_ready(states)
 
-        def greedy(logits):
-            return jax.device_put(jnp.argmax(logits, -1).astype(jnp.int32),
-                                  self.step_tok_sh)
+            def greedy(logits):
+                return jax.device_put(
+                    jnp.argmax(logits, -1).astype(jnp.int32),
+                    self.step_tok_sh)
 
-        fed: List = []                   # decode inputs, in order
-        done = {name: 0 for name in RUNGS}
-        logits_steps, switches = [], []
-        catch_up = 0
-        tok = greedy(first)
-        active = schedule[0]
-        t0 = time.perf_counter()
-        for i, want in enumerate(schedule):
-            if want != active:
-                switches.append((i, active, want, queue_depth(i, n_tokens)))
-                active = want
-            # replay the tokens this rung missed while the other one served
-            for past in fed[done[active]:]:
-                _, states[active] = self.step_fns[active](
-                    self.params, states[active], past)
-                catch_up += 1
-            fed.append(tok)
-            logits, states[active] = self.step_fns[active](
-                self.params, states[active], tok)
-            done[active] = len(fed)
-            logits_steps.append(logits)
-            tok = greedy(logits)
-        jax.block_until_ready(tok)
-        decode_s = time.perf_counter() - t0
-        return ServeResult(
-            prefill_logits=np.asarray(first, np.float32),
-            inputs=np.stack([np.asarray(t) for t in fed]),
-            logits=np.stack([np.asarray(x, np.float32)
-                             for x in logits_steps]),
-            rungs=schedule, switches=switches, catch_up_steps=catch_up,
-            compile_s=dict(self.compile_s), prefill_s=prefill_s,
-            decode_s=decode_s)
+            fed: List = []                   # decode inputs, in order
+            done = {name: 0 for name in RUNGS}
+            logits_steps = []
+            tok = greedy(first)
+            active = schedule[0]
+            for i, want in enumerate(schedule):
+                if want != active:
+                    # replay the tokens this rung missed while the other
+                    # one served
+                    missed = range(done[want], len(fed))
+                    with rec.span("serve.switch", step=i, frm=active,
+                                  to=want, depth=queue_depth(i, n_tokens),
+                                  replayed=len(missed)):
+                        for k in missed:
+                            with rec.span("serve.catchup", rung=want,
+                                          pos=p + k):
+                                _, states[want] = self.step_fns[want](
+                                    self.params, states[want], fed[k])
+                    active = want
+                fed.append(tok)
+                with rec.span("serve.decode", rung=active, step=i,
+                              pos=p + i):
+                    logits, states[active] = self.step_fns[active](
+                        self.params, states[active], tok)
+                    tok = greedy(logits)
+                done[active] = len(fed)
+                logits_steps.append(logits)
+            with rec.span("serve.decode.wait"):
+                jax.block_until_ready(tok)
+            rec.count("catch_up_steps", sum(s.name == "serve.catchup"
+                                            for s in rec.spans))
+
+            with rec.span("serve.handoff"):
+                def to_host(x):
+                    with rec.span("serve.handoff.transfer", bytes=x.nbytes):
+                        out = np.asarray(x)
+                    rec.count("host_bytes", x.nbytes)
+                    return out
+
+                host_first = to_host(first)
+                host_fed = [to_host(t) for t in fed]
+                host_logits = [to_host(x) for x in logits_steps]
+                with rec.span("serve.handoff.stack"):
+                    res = ServeResult(
+                        prefill_logits=host_first.astype(np.float32,
+                                                         copy=False),
+                        inputs=np.stack(host_fed),
+                        logits=np.stack([x.astype(np.float32, copy=False)
+                                         for x in host_logits]),
+                        rungs=schedule, spans=rec.spans,
+                        counters=rec.counters)
+        return res
 
 
 def reference_forwards(cfg, window: int) -> Dict[str, object]:
@@ -360,7 +430,24 @@ def run(args: argparse.Namespace):
           f"{res.prefill_s:.3f}s; decoded {args.tokens} tokens x batch "
           f"{args.batch} ({res.catch_up_steps} catch-up steps) in "
           f"{res.decode_s:.3f}s on {dev['count']} {dev['kind']}")
+    print(call_line(res))
     return plane, res
+
+
+def call_line(res: ServeResult) -> str:
+    """One line per call, read from its spans: prefill, decode and
+    hand-off times, the bytes handed to the host and their rate over the
+    transfers."""
+    ms = {name: 1e3 * sum(s.seconds for s in res.spans if s.name == name)
+          for name in ("serve.handoff", "serve.handoff.transfer",
+                       "serve.handoff.stack")}
+    mb = res.counters["host_bytes"] / 1e6
+    return (f"call {res.spans[0].call}: prefill {res.prefill_s * 1e3:.1f} "
+            f"ms, decode {res.decode_s * 1e3:.1f} ms, hand-off "
+            f"{ms['serve.handoff']:.1f} ms (transfers "
+            f"{ms['serve.handoff.transfer']:.1f} ms, stacking "
+            f"{ms['serve.handoff.stack']:.1f} ms); {mb:.2f} MB to the host "
+            f"at {mb / ms['serve.handoff.transfer']:.3f} GB/s")
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:

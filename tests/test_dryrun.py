@@ -141,3 +141,4 @@ def test_serve_launcher_subprocess():
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "switch accurate -> fast" in proc.stdout
     assert "decoded 9 tokens" in proc.stdout
+    assert "MB to the host" in proc.stdout
