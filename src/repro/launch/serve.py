@@ -30,6 +30,7 @@ import os
 import time
 from typing import Dict, List, Optional, Sequence
 
+import ml_dtypes
 import numpy as np
 
 from .spans import Recorder, Span, seconds
@@ -88,7 +89,8 @@ class ServeResult:
     logits: np.ndarray              # (n, B, V) logits of each decode step
     rungs: List[str]                # rung that served each decode step
     spans: List[Span]               # the call's spans (see ``generate``)
-    counters: Dict[str, int]        # host_bytes, catch_up_steps
+    counters: Dict[str, int]        # host_bytes, catch_up_steps,
+                                    # handoff_overlapped
 
     def _first(self, name: str) -> Span:
         return next(s for s in self.spans if s.name == name)
@@ -103,7 +105,7 @@ class ServeResult:
     @property
     def decode_s(self) -> float:
         """Every decode step: from the first dispatch until the device has
-        done them."""
+        done them (the logits' hand-off runs inside that interval)."""
         return seconds(self._first("serve.decode"),
                        self._first("serve.decode.wait"))
 
@@ -123,6 +125,18 @@ class ServeResult:
     def tokens(self) -> np.ndarray:
         """(n, B) greedy tokens generated by the decode steps."""
         return self.logits.argmax(-1).astype(np.int32)
+
+
+def widen(x: np.ndarray, out: np.ndarray) -> None:
+    """Write ``x`` into the float32 array ``out``, exactly.  A bfloat16
+    value's bits are the high half of the same float32's, so they are
+    shifted into place (zeros, infinities, NaN payloads and subnormals
+    included); any other dtype is copied."""
+    if x.dtype == ml_dtypes.bfloat16:
+        np.left_shift(x.view(np.uint16), 16, out=out.view(np.uint32),
+                      dtype=np.uint32)
+    else:
+        np.copyto(out, x)
 
 
 def rung_programs(model, planner, *, batch: int, prompt_len: int,
@@ -225,8 +239,17 @@ class ServingPlane:
 
     def generate(self, tokens, n_tokens: int) -> ServeResult:
         """Prefill every rung on ``tokens``, then greedily decode
-        ``n_tokens`` steps under :func:`rung_schedule`, then hand the
-        logits to the host.
+        ``n_tokens`` steps under :func:`rung_schedule`, handing each
+        step's logits to the host while the device runs later steps.
+
+        Each served step's logits and each fed token start their copy to
+        the host as they are dispatched.  The host drains them in step
+        order: after each served step's dispatch it takes every earlier
+        step the device has done, and after the last dispatch the rest.
+        Draining a step waits for its copy, widens its logits into the
+        float32 result (:func:`widen`) and drops the device array, so the
+        hand-off runs beside the decode steps still on the device rather
+        than after them.  Nothing in the hand-off compiles.
 
         The call's spans, all under one ``serve.call`` with the call's id:
         ``serve.prefill`` (``rung``) around each rung's prefill dispatch
@@ -235,12 +258,14 @@ class ServingPlane:
         step, from its dispatch through the greedy pick; ``serve.switch``
         (``step``, ``frm``, ``to``, ``depth``, ``replayed``) around a
         switch and the ``serve.catchup`` steps (``rung``, ``pos``) that
-        replay the tokens the new rung missed; ``serve.decode.wait`` until
-        the device has done them; ``serve.handoff`` from there to the
-        result, with a ``serve.handoff.transfer`` (``bytes``) around each
-        array copied to the host and ``serve.handoff.stack`` around their
-        float32 conversion and stacking.  Counters: ``host_bytes`` copied to the host,
-        ``catch_up_steps``."""
+        replay the tokens the new rung missed; ``serve.handoff`` around
+        each drain, between served steps and inside ``serve.decode.wait``
+        (from the last dispatch until the device has done every step),
+        with a ``serve.handoff.transfer`` (``bytes``) around each wait for
+        an array's copy to the host and a ``serve.handoff.widen`` around
+        each logit array's widening.  Counters: ``host_bytes`` copied to
+        the host, ``catch_up_steps``, ``handoff_overlapped``: decode logit
+        arrays widened while the device still had decode steps to run."""
         import jax
         import jax.numpy as jnp
 
@@ -255,6 +280,7 @@ class ServingPlane:
                                                                 tokens)
                 if name == "accurate":
                     first = last
+                    first.copy_to_host_async()
             with rec.span("serve.prefill.wait"):
                 jax.block_until_ready(states)
 
@@ -263,9 +289,37 @@ class ServingPlane:
                     jnp.argmax(logits, -1).astype(jnp.int32),
                     self.step_tok_sh)
 
+            def to_host(x):
+                with rec.span("serve.handoff.transfer", bytes=x.nbytes):
+                    out = np.asarray(x)
+                rec.count("host_bytes", x.nbytes)
+                return out
+
+            def widened(x, out):
+                host = to_host(x)
+                with rec.span("serve.handoff.widen"):
+                    widen(host, out)
+
             fed: List = []                   # decode inputs, in order
+            logits_steps = []                # served steps' device logits
+            prefill_logits = np.empty(first.shape, np.float32)
+            logits = np.empty((n_tokens,) + first.shape, np.float32)
+            host_fed, drained, overlapped = [], 0, 0
+
+            def drain(wait: bool) -> None:
+                """Hand the served steps' inputs and logits to the host in
+                step order: those the device has done, or with ``wait``
+                all of them."""
+                nonlocal drained, overlapped
+                while drained < len(logits_steps) and (
+                        wait or logits_steps[drained].is_ready()):
+                    host_fed.append(to_host(fed[drained]))
+                    widened(logits_steps[drained], logits[drained])
+                    logits_steps[drained] = None   # its HBM may go
+                    drained += 1
+                    overlapped += not tok.is_ready()
+
             done = {name: 0 for name in RUNGS}
-            logits_steps = []
             tok = greedy(first)
             active = schedule[0]
             for i, want in enumerate(schedule):
@@ -283,37 +337,32 @@ class ServingPlane:
                                     self.params, states[want], fed[k])
                     active = want
                 fed.append(tok)
+                tok.copy_to_host_async()
                 with rec.span("serve.decode", rung=active, step=i,
                               pos=p + i):
-                    logits, states[active] = self.step_fns[active](
+                    step_logits, states[active] = self.step_fns[active](
                         self.params, states[active], tok)
-                    tok = greedy(logits)
+                    step_logits.copy_to_host_async()
+                    tok = greedy(step_logits)
                 done[active] = len(fed)
-                logits_steps.append(logits)
-            with rec.span("serve.decode.wait"):
-                jax.block_until_ready(tok)
+                logits_steps.append(step_logits)
+                if logits_steps[drained].is_ready():
+                    with rec.span("serve.handoff"):
+                        drain(wait=False)
             rec.count("catch_up_steps", sum(s.name == "serve.catchup"
                                             for s in rec.spans))
 
-            with rec.span("serve.handoff"):
-                def to_host(x):
-                    with rec.span("serve.handoff.transfer", bytes=x.nbytes):
-                        out = np.asarray(x)
-                    rec.count("host_bytes", x.nbytes)
-                    return out
+            with rec.span("serve.decode.wait"):
+                with rec.span("serve.handoff"):
+                    widened(first, prefill_logits)
+                    drain(wait=True)
+                rec.count("handoff_overlapped", overlapped)
+                jax.block_until_ready(tok)
 
-                host_first = to_host(first)
-                host_fed = [to_host(t) for t in fed]
-                host_logits = [to_host(x) for x in logits_steps]
-                with rec.span("serve.handoff.stack"):
-                    res = ServeResult(
-                        prefill_logits=host_first.astype(np.float32,
-                                                         copy=False),
-                        inputs=np.stack(host_fed),
-                        logits=np.stack([x.astype(np.float32, copy=False)
-                                         for x in host_logits]),
-                        rungs=schedule, spans=rec.spans,
-                        counters=rec.counters)
+            res = ServeResult(prefill_logits=prefill_logits,
+                              inputs=np.stack(host_fed), logits=logits,
+                              rungs=schedule, spans=rec.spans,
+                              counters=rec.counters)
         return res
 
 
@@ -436,18 +485,20 @@ def run(args: argparse.Namespace):
 
 def call_line(res: ServeResult) -> str:
     """One line per call, read from its spans: prefill, decode and
-    hand-off times, the bytes handed to the host and their rate over the
-    transfers."""
+    hand-off times (the hand-off runs inside decode's interval), the
+    hand-off's waits for the copies and its widening, how many steps were
+    widened while decode still ran, and the bytes handed to the host."""
     ms = {name: 1e3 * sum(s.seconds for s in res.spans if s.name == name)
           for name in ("serve.handoff", "serve.handoff.transfer",
-                       "serve.handoff.stack")}
+                       "serve.handoff.widen")}
     mb = res.counters["host_bytes"] / 1e6
     return (f"call {res.spans[0].call}: prefill {res.prefill_s * 1e3:.1f} "
             f"ms, decode {res.decode_s * 1e3:.1f} ms, hand-off "
             f"{ms['serve.handoff']:.1f} ms (transfers "
-            f"{ms['serve.handoff.transfer']:.1f} ms, stacking "
-            f"{ms['serve.handoff.stack']:.1f} ms); {mb:.2f} MB to the host "
-            f"at {mb / ms['serve.handoff.transfer']:.3f} GB/s")
+            f"{ms['serve.handoff.transfer']:.1f} ms, widening "
+            f"{ms['serve.handoff.widen']:.1f} ms; "
+            f"{res.counters['handoff_overlapped']} of {len(res.rungs)} "
+            f"steps while decode ran); {mb:.2f} MB to the host")
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:

@@ -6,6 +6,8 @@ including the accurate rung's after the switch back, which first replays
 the tokens the fast rung served, are held to that rung's own
 teacher-forced ``forward`` over the same tokens.  The call's spans and
 counters describe what it did, and reach a profiler trace as host events.
+A reduced plane at bfloat16 hands its logits over widened, exactly, to
+float32, each step's in its own row.
 """
 
 import dataclasses
@@ -15,6 +17,7 @@ from collections import Counter
 from pathlib import Path
 
 import jax
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -154,8 +157,12 @@ def test_prefill_and_decode_times_are_their_spans(served):
     assert all(decode[0].start_ns <= s.start_ns and s.end_ns <= d_wait.start_ns
                for s in res.spans
                if s.name in ("serve.decode", "serve.switch", "serve.catchup"))
-    (handoff,) = spans_named(res, "serve.handoff")
-    assert handoff.start_ns >= d_wait.end_ns
+    # the hand-off drains between served steps, and last inside the wait
+    *between, final = spans_named(res, "serve.handoff")
+    assert res.spans[final.parent] is d_wait
+    assert d_wait.start_ns <= final.start_ns <= final.end_ns <= d_wait.end_ns
+    assert all(res.spans[h.parent].name == "serve.call"
+               and decode[0].end_ns <= h.start_ns for h in between)
     assert 0 < res.prefill_s and 0 < res.decode_s
 
 
@@ -170,9 +177,40 @@ def test_handoff_counts_the_bytes_it_copies(served):
     transfers = spans_named(res, "serve.handoff.transfer")
     assert len(transfers) == 1 + 2 * TOKENS
     assert sum(s.attrs["bytes"] for s in transfers) == want
-    (stack,) = spans_named(res, "serve.handoff.stack")
-    assert {res.spans[s.parent].name for s in transfers + [stack]} == {
+    assert not spans_named(res, "serve.handoff.stack")
+    widen = spans_named(res, "serve.handoff.widen")
+    assert len(widen) == 1 + TOKENS          # one per logit array
+    assert {res.spans[s.parent].name for s in transfers + widen} == {
         "serve.handoff"}
+
+
+def test_done_steps_drain_between_dispatches(served, monkeypatch):
+    """With every step done by the time its dispatch returns, each served
+    step is handed over right after its own dispatch, and the wait holds
+    only the prefill's logits.  The result is the same, bit for bit."""
+    res, _, plane, prompt = served
+    for rung, fn in list(plane.step_fns.items()):
+        monkeypatch.setitem(plane.step_fns, rung,
+                            lambda *a, fn=fn: jax.block_until_ready(fn(*a)))
+    synced = plane.generate(prompt, TOKENS)
+    *between, final = spans_named(synced, "serve.handoff")
+    assert len(between) == TOKENS
+    inside = Counter(s.name for s in synced.spans
+                     if s.parent >= 0 and synced.spans[s.parent] is final)
+    assert inside == {"serve.handoff.transfer": 1, "serve.handoff.widen": 1}
+    assert synced.counters["host_bytes"] == res.counters["host_bytes"]
+    for got, want in ((synced.logits, res.logits),
+                      (synced.prefill_logits, res.prefill_logits),
+                      (synced.inputs, res.inputs)):
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+
+
+def test_handoff_overlap_is_counted(served):
+    """At most every decode step's logits are widened while the last
+    step still runs; none on a device that finishes first."""
+    res = served[0]
+    assert 0 <= res.counters["handoff_overlapped"] <= TOKENS
 
 
 def test_call_line_reads_the_spans(served):
@@ -182,6 +220,86 @@ def test_call_line_reads_the_spans(served):
                            f"{res.prefill_s * 1e3:.1f} ms, decode "
                            f"{res.decode_s * 1e3:.1f} ms, hand-off ")
     assert f"{res.counters['host_bytes'] / 1e6:.2f} MB to the host" in line
+    assert "widening" in line
+    assert (f"{res.counters['handoff_overlapped']} of {TOKENS} steps "
+            "while decode ran") in line
+
+
+def test_another_length_compiles_nothing(served):
+    """The hand-off is host work: a call of another length runs only the
+    programs the first call built."""
+    _, _, plane, prompt = served
+    compiled = []
+
+    def on(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        res = plane.generate(prompt, TOKENS - 5)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert res.logits.shape == (TOKENS - 5, BATCH, 512)
+    assert compiled == []
+
+
+BF16_SPECIALS = [0.0, -0.0, np.inf, -np.inf, np.nan, -np.nan, 1.0, -2.5,
+                 3.0e38, -1.0e-38, 1.0e-40, -9.2e-41, 65504.0, 1.0e-45]
+
+
+@pytest.mark.parametrize("values", ["specials", "all_bits", "float32"])
+def test_widen_is_astype_bit_for_bit(values):
+    """Widening a bfloat16 array matches ml_dtypes' ``astype(float32)``
+    to the bit: signed zeros, infinities, NaNs, subnormals and ordinary
+    values alike.  A float32 array is copied as it is."""
+    bf16 = ml_dtypes.bfloat16
+    if values == "specials":
+        x = np.array(BF16_SPECIALS, np.float32).astype(bf16).reshape(2, 7)
+        assert (np.abs(x.astype(np.float32)) < 1.18e-38).any()   # subnormal
+    elif values == "all_bits":
+        x = np.arange(2**16, dtype=np.uint16).view(bf16).reshape(256, 256)
+    else:
+        x = np.array(BF16_SPECIALS, np.float32).reshape(2, 7)
+    out = np.full(x.shape, 7.0, np.float32)
+    serve.widen(x, out)
+    np.testing.assert_array_equal(out.view(np.uint32),
+                                  x.astype(np.float32).view(np.uint32))
+
+
+@pytest.fixture(scope="module")
+def served_bf16():
+    cfg = dataclasses.replace(reduced_config("internlm2-1.8b"),
+                              dtype="bfloat16")
+    plane = serve.ServingPlane(
+        cfg, mesh=serve.build_mesh(devices=jax.devices()[:1]),
+        window=WINDOW, batch=BATCH, prompt_len=PROMPT, max_new=TOKENS)
+    return plane.generate(plane.prompt(BATCH, PROMPT), TOKENS)
+
+
+def test_bf16_logits_arrive_as_exact_float32(served_bf16):
+    """The plane's logits leave the device at bfloat16 and reach the
+    result as float32 that round-trip through bfloat16 unchanged."""
+    res = served_bf16
+    assert res.logits.dtype == res.prefill_logits.dtype == np.float32
+    assert res.logits.shape == (TOKENS, BATCH, 512)
+    assert res.prefill_logits.shape == (BATCH, 512)
+    assert res.counters["host_bytes"] == (2 * BATCH * 512 * (TOKENS + 1)
+                                          + 4 * BATCH * TOKENS)
+    for a in (res.logits, res.prefill_logits):
+        assert np.isfinite(a).all()
+        back = a.astype(ml_dtypes.bfloat16).astype(np.float32)
+        np.testing.assert_array_equal(back.view(np.uint32), a.view(np.uint32))
+
+
+def test_bf16_step_logits_land_in_their_row(served_bf16):
+    """Step i's greedy pick, made on the device from its bfloat16 logits,
+    is the token fed to step i + 1: the host's argmax over row i of the
+    widened logits finds it, so row i holds step i's logits."""
+    res = served_bf16
+    np.testing.assert_array_equal(res.tokens[:-1], res.inputs[1:])
+    np.testing.assert_array_equal(res.prefill_logits.argmax(-1),
+                                  res.inputs[0])
 
 
 def benchmark_annotations():
@@ -220,7 +338,7 @@ def test_spans_reach_the_profiler_trace(served, tmp_path):
     host = Counter(e.name for plane_ in data.planes
                    if plane_.name.startswith("/host:")
                    for line in plane_.lines for e in line.events)
-    assert host["serve.handoff"] == 1
+    assert host["serve.handoff"] == len(spans_named(res, "serve.handoff"))
     assert host["serve.decode"] == 3
     assert host["serve.catchup"] == res.catch_up_steps
     assert np.isfinite(res.logits).all()
