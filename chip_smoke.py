@@ -2,6 +2,7 @@
 
     python chip_smoke.py             # one chip: model plane + simulation plane
     python chip_smoke.py --chips 4   # four chips: sharded serving vs one chip
+    python chip_smoke.py --arch deepseek-moe-16b   # one chip: the MoE share
 
 Run from the root of a checkout; everything is built from tracked files
 and seeds.  The script refuses to run anywhere but a TPU (there is no CPU
@@ -38,6 +39,19 @@ One chip, four phases:
   sketch resolution on streamed p95s, absolute 1e-3 on compliance.  An
   f32 evaluation misses the relative bound by orders of magnitude.
 
+``--arch deepseek-moe-16b`` (one chip) runs one phase, **serve_moe**:
+the serving plane on one expert-parallel rank's share of deepseek-moe-16b
+(14 of 28 layers, routed experts 0-7 of 64 held, as
+``chipbench/configs/deepseek-moe-16b.json`` cuts it) at batch 64, prompt
+128, 64 new tokens, checked for finite logits and both switches, with its
+call line and ``moe_held_assignments`` beside that count's expectation
+under uniform routing.  Then the same plane at float32 and the highest
+matmul precision, each rung held to the teacher-forced float32
+``forward``, which computes the experts by the training path's dense
+dispatch: the accurate rung by the decode-parity criteria above, the fast
+rung (whose window does not bind at 192 positions) by the int8 floor
+above, a mean correlation of at least 0.85 (0.917 measured on a v5e).
+
 Four chips (``--chips 4``): the serving launcher on a (data, model) =
 (1, 4) mesh and the check that its parameter shards sit on four devices;
 then the comparison, at float32 as above, of the same seed served on the
@@ -58,6 +72,9 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent
 
 ARCH = "internlm2-1.8b"
+MOE_ARCH = "deepseek-moe-16b"
+MOE_CUT = dict(num_layers=14, experts_held=8)   # rank 0 of 8, layers 15-28
+MOE_SHAPE = dict(batch=64, prompt_len=128, tokens=64, window=256)
 LOGIT_REL_TOL = 0.05        # max |diff| / max |logit|, float32 decode parity
 LOGIT_MIN_CORR = 0.999
 INT8_MIN_MEAN_CORR = 0.85   # fast rung (int8 KV) vs its window forward
@@ -233,6 +250,61 @@ def phase_serve() -> None:
               f"bf16 {rung} rung at one layer: mean correlation {mean!r}")
 
 
+def phase_serve_moe() -> None:
+    import argparse
+    import dataclasses
+
+    import jax
+    import numpy as np
+
+    from repro.launch import serve
+    from repro.models.registry import get_config
+
+    cfg = dataclasses.replace(get_config(MOE_ARCH), **MOE_CUT)
+    args = argparse.Namespace(**MOE_SHAPE)
+    devices = jax.devices()[:1]
+    log(f"{MOE_ARCH}: {cfg.num_layers} layers, experts "
+        f"{cfg.expert_first}-{cfg.expert_first + cfg.held_experts - 1} of "
+        f"{cfg.num_experts} held; batch {args.batch}, prompt "
+        f"{args.prompt_len}, {args.tokens} new tokens")
+    plane = serve.ServingPlane(
+        cfg, mesh=serve.build_mesh(devices=devices), window=args.window,
+        batch=args.batch, prompt_len=args.prompt_len, max_new=args.tokens)
+    prompt = plane.prompt(args.batch, args.prompt_len)
+    res = plane.generate(prompt, args.tokens)
+    report_serve(plane, res)
+    log(f"  {serve.call_line(res)}")
+    moe_layers = cfg.num_layers - cfg.first_dense_layers
+    steps = len(res.rungs) + res.catch_up_steps
+    held = res.counters["moe_held_assignments"]
+    expect = (args.batch * cfg.moe_top_k * moe_layers * steps
+              * cfg.held_experts / cfg.num_experts)
+    log(f"  moe_held_assignments {held}; uniform routing expects "
+        f"{expect:.0f} (ratio {held / expect:.3f})")
+    most = (args.batch * min(cfg.moe_top_k, cfg.held_experts) * moe_layers
+            * steps)
+    check(0 < held <= most, f"moe_held_assignments {held} outside (0, {most}]")
+    del plane
+    gc.collect()
+
+    ref_plane, ref = reference_plane(cfg, args, devices)
+    check(ref.switches == res.switches, "float32 run switched differently")
+    with jax.default_matmul_precision("highest"):
+        agree = serve.rung_agreement(serve.reference_forwards(cfg, args.window),
+                                     ref_plane.params, np.asarray(prompt), ref)
+    del ref_plane
+    gc.collect()
+    report_agreement("float32", agree)
+    rel, mn, _ = agree["accurate"]["accurate"]
+    check(rel <= LOGIT_REL_TOL and mn >= LOGIT_MIN_CORR,
+          "accurate decode disagrees with the forward reference")
+    # 192 positions: the 256 window does not bind, so the fast rung
+    # differs from its forward by its int8 cache alone
+    mean = agree["fast"]["fast"][2]
+    check(mean >= INT8_MIN_MEAN_CORR,
+          f"fast (int8 KV) decode: mean correlation {mean!r}")
+
+
 def param_bytes_by_device(params):
     import jax
 
@@ -395,6 +467,9 @@ def main() -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the sharded serving path and its "
                          "one-chip comparison")
+    ap.add_argument("--arch", choices=(ARCH, MOE_ARCH), default=ARCH,
+                    help=f"{MOE_ARCH}: run only its serving phase, on one "
+                         f"chip")
     args = ap.parse_args()
     if not (ROOT / "src" / "repro").is_dir():
         log(f"chip_smoke: no src/repro beside {__file__}; run it from the "
@@ -415,7 +490,9 @@ def main() -> int:
         return 2
     log(f"compile cache: {use_compile_cache()}")
 
-    if args.chips == 4:
+    if args.arch == MOE_ARCH:
+        phases = [("serve_moe", phase_serve_moe)]
+    elif args.chips == 4:
         phases = [("serve_sharded", phase_serve_sharded)]
     else:
         phases = [("serve", phase_serve), ("validate", phase_validate),

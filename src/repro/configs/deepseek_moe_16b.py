@@ -1,6 +1,9 @@
 """deepseek-moe-16b [moe]: 28L d_model=2048 16H (kv=16) d_ff=1408
 vocab=102400, 64 routed experts top-6 + 2 shared experts, fine-grained;
-first layer is a dense FFN (first_k_dense_replace=1).  [arXiv:2401.06066]
+first layer is a dense FFN (first_k_dense_replace=1).  Softmax routing
+with greedy top-6 whose probabilities are not rescaled (norm_topk_prob
+false), RMSNorm eps 1e-6, untied embedding and head.  [arXiv:2401.06066;
+huggingface.co/deepseek-ai/deepseek-moe-16b-base]
 """
 
 from ..models.common import ModelConfig
@@ -25,7 +28,9 @@ def config() -> ModelConfig:
         moe_top_k=6,
         first_dense_layers=1,
         dense_ff=10944,            # the dense layer's FFN (paper table 2)
+        norm_topk_prob=False,
         rope_theta=1.0e4,
+        norm_eps=1.0e-6,
     )
 
 

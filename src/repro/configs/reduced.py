@@ -38,6 +38,8 @@ def reduced_config(arch_id: str, *, layers: int = 2) -> ModelConfig:
             num_shared_experts=min(1, cfg.num_shared_experts),
             first_dense_layers=min(1, cfg.first_dense_layers),
             dense_ff=512 if cfg.dense_ff else 0,
+            expert_first=0,
+            experts_held=0,
         )
     if cfg.ssm_state:
         over.update(ssm_state=8)

@@ -67,14 +67,15 @@ def _mlp_flops(cfg: ModelConfig, s: int, d_ff: Optional[int] = None) -> float:
 
 def _moe_flops(cfg: ModelConfig, s: int) -> float:
     d, f_dim, e, k = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.moe_top_k
+    held = cfg.held_experts
     router = 2 * s * d * e
     if cfg.moe_impl == "gshard":
-        # capacity buffers: E * C tokens, C = s*k*cf/E
-        cap_tokens = s * k * cfg.capacity_factor
+        # capacity buffers of the held experts: H * C tokens, C = s*k*cf/E
+        cap_tokens = s * k * cfg.capacity_factor * held / e
         expert = 2 * cap_tokens * d * f_dim * 3
     else:
-        # dense dispatch: every expert touches every token
-        expert = 2 * s * e * d * f_dim * 3
+        # dense dispatch: every held expert touches every token
+        expert = 2 * s * held * d * f_dim * 3
     shared = 0.0
     if cfg.num_shared_experts:
         shared = 2 * s * d * (f_dim * cfg.num_shared_experts) * 3

@@ -78,7 +78,8 @@ def rung_configs(base, window: int) -> Dict[str, object]:
         "accurate": base,
         "fast": dataclasses.replace(
             base, sliding_window=window,
-            kv_cache_dtype="int8" if base.family in ("dense", "hybrid") else ""),
+            kv_cache_dtype=("int8" if base.family in ("dense", "hybrid", "moe")
+                            else "")),
     }
 
 
@@ -90,7 +91,8 @@ class ServeResult:
     rungs: List[str]                # rung that served each decode step
     spans: List[Span]               # the call's spans (see ``generate``)
     counters: Dict[str, int]        # host_bytes, catch_up_steps,
-                                    # handoff_overlapped
+                                    # handoff_overlapped; MoE models:
+                                    # moe_held_assignments
 
     def _first(self, name: str) -> Span:
         return next(s for s in self.spans if s.name == name)
@@ -263,9 +265,14 @@ class ServingPlane:
         (from the last dispatch until the device has done every step),
         with a ``serve.handoff.transfer`` (``bytes``) around each wait for
         an array's copy to the host and a ``serve.handoff.widen`` around
-        each logit array's widening.  Counters: ``host_bytes`` copied to
-        the host, ``catch_up_steps``, ``handoff_overlapped``: decode logit
-        arrays widened while the device still had decode steps to run."""
+        each logit array's widening; for a MoE model ``serve.moe.load``
+        (``bytes``) around reading the decode states' expert counters
+        after the wait.  Counters: ``host_bytes`` copied to the host,
+        ``catch_up_steps``, ``handoff_overlapped``: decode logit arrays
+        widened while the device still had decode steps to run;
+        ``moe_held_assignments`` (MoE): the (token, expert) assignments of
+        every decode step of the call, catch-up steps included, that
+        landed on the experts this chip holds, summed over layers."""
         import jax
         import jax.numpy as jnp
 
@@ -358,6 +365,13 @@ class ServingPlane:
                     drain(wait=True)
                 rec.count("handoff_overlapped", overlapped)
                 jax.block_until_ready(tok)
+            if self.cfg.family == "moe":
+                counts = [seg["held"] for name in RUNGS
+                          for seg in states[name]["segments"]]
+                with rec.span("serve.moe.load",
+                              bytes=sum(c.nbytes for c in counts)):
+                    rec.count("moe_held_assignments",
+                              sum(int(np.asarray(c).sum()) for c in counts))
 
             res = ServeResult(prefill_logits=prefill_logits,
                               inputs=np.stack(host_fed), logits=logits,
@@ -487,7 +501,8 @@ def call_line(res: ServeResult) -> str:
     """One line per call, read from its spans: prefill, decode and
     hand-off times (the hand-off runs inside decode's interval), the
     hand-off's waits for the copies and its widening, how many steps were
-    widened while decode still ran, and the bytes handed to the host."""
+    widened while decode still ran, the bytes handed to the host, and for
+    a MoE model the assignments its held experts served."""
     ms = {name: 1e3 * sum(s.seconds for s in res.spans if s.name == name)
           for name in ("serve.handoff", "serve.handoff.transfer",
                        "serve.handoff.widen")}
@@ -498,7 +513,9 @@ def call_line(res: ServeResult) -> str:
             f"{ms['serve.handoff.transfer']:.1f} ms, widening "
             f"{ms['serve.handoff.widen']:.1f} ms; "
             f"{res.counters['handoff_overlapped']} of {len(res.rungs)} "
-            f"steps while decode ran); {mb:.2f} MB to the host")
+            f"steps while decode ran); {mb:.2f} MB to the host"
+            + (f"; moe_held_assignments {res.counters['moe_held_assignments']}"
+               if "moe_held_assignments" in res.counters else ""))
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:

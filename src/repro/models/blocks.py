@@ -132,7 +132,10 @@ def block_apply_seq(
                 enc_k, enc_v = attn.encode_cross_kv(params["cross"], enc_out)
                 state = {"kv": state, "enc_k": enc_k, "enc_v": enc_v}
         h = rmsnorm(x, params["mlp_norm"], cfg.norm_eps)
-        if block_type == "moe":
+        if block_type == "moe" and cache_len > 0:
+            h, _ = moe_mod.moe_serve(params["moe"], h, cfg)
+            state = moe_state(state)
+        elif block_type == "moe":
             h, aux = moe_mod.moe_apply(params["moe"], h, cfg)
         else:
             h = mlp_apply(params["mlp"], h, cfg.act)
@@ -184,12 +187,21 @@ def block_apply_seq(
 # ---------------------------------------------------------------------------
 
 
+def moe_state(kv: Any) -> Dict[str, Any]:
+    """A MoE block's decode state: its KV cache, and ``held``, the
+    (token, expert) assignments its decode steps have routed to the experts
+    this chip holds since the prefill (an int32 counter on the device)."""
+    return {"kv": kv, "held": jnp.zeros((), jnp.int32)}
+
+
 def block_init_state(
     cfg: ModelConfig, block_type: str, batch: int, cache_len: int,
     dtype: jnp.dtype, *, enc_len: int = 0,
 ) -> Any:
-    if block_type in ("dense", "moe"):
+    if block_type == "dense":
         return attn.init_cache(cfg, batch, cache_len, dtype)
+    if block_type == "moe":
+        return moe_state(attn.init_cache(cfg, batch, cache_len, dtype))
     if block_type == "hybrid":
         return {
             "kv": attn.init_cache(cfg, batch, cache_len, dtype),
@@ -220,17 +232,22 @@ def block_apply_decode(
     ssm_mode: str = "serial",
 ) -> Tuple[jax.Array, Any]:
     """One-token decode through one block.  x: (B, 1, D)."""
-    if block_type in ("dense", "moe"):
+    if block_type == "dense":
         h = rmsnorm(x, params["attn_norm"], cfg.norm_eps)
         h, new_state = attn.decode_attention(params["attn"], h, state, cfg,
                                              position=position)
         x = x + h
         h = rmsnorm(x, params["mlp_norm"], cfg.norm_eps)
-        if block_type == "moe":
-            h, _ = moe_mod.moe_apply(params["moe"], h, cfg)
-        else:
-            h = mlp_apply(params["mlp"], h, cfg.act)
-        return x + h, new_state
+        return x + mlp_apply(params["mlp"], h, cfg.act), new_state
+
+    if block_type == "moe":
+        h = rmsnorm(x, params["attn_norm"], cfg.norm_eps)
+        h, new_kv = attn.decode_attention(params["attn"], h, state["kv"], cfg,
+                                          position=position)
+        x = x + h
+        h = rmsnorm(x, params["mlp_norm"], cfg.norm_eps)
+        h, held = moe_mod.moe_serve(params["moe"], h, cfg)
+        return x + h, {"kv": new_kv, "held": state["held"] + held}
 
     if block_type == "hybrid":
         h = rmsnorm(x, params["mix_norm"], cfg.norm_eps)

@@ -52,8 +52,12 @@ class ModelConfig:
     moe_top_k: int = 0
     first_dense_layers: int = 0  # leading dense-FFN layers (DeepSeek-MoE)
     dense_ff: int = 0            # their hidden size
-    moe_impl: str = "dense"      # dense | gshard   (dispatch implementation)
+    moe_impl: str = "dense"      # dense | gshard   (training dispatch; serving
+                                 # always runs the dropless grouped dispatch)
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # rescale the top-k probabilities to sum 1
+    expert_first: int = 0        # this chip's share of the routed experts:
+    experts_held: int = 0        # [first, first + held); 0 = all of them
 
     # --- SSM / hybrid (Mamba-style selective scan) ---
     ssm_state: int = 0
@@ -100,6 +104,13 @@ class ModelConfig:
             )
         if self.family in ("moe",) and (self.num_experts <= 0 or self.moe_top_k <= 0):
             raise ValueError(f"{self.arch_id}: moe family needs experts/top_k")
+        if self.num_experts and not (
+                0 <= self.expert_first
+                and self.expert_first + self.held_experts <= self.num_experts):
+            raise ValueError(
+                f"{self.arch_id}: held experts [{self.expert_first}, "
+                f"{self.expert_first + self.held_experts}) outside the "
+                f"{self.num_experts} routed")
 
     # -- derived ------------------------------------------------------------
 
@@ -110,6 +121,11 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def ssm_inner(self) -> int:
@@ -175,6 +191,8 @@ class ModelConfig:
                 num_shared_experts=min(self.num_shared_experts, 1),
                 first_dense_layers=min(self.first_dense_layers, 1),
                 dense_ff=min(self.dense_ff, 256) if self.dense_ff else 0,
+                expert_first=0,
+                experts_held=0,
             )
         if self.encoder_layers:
             small["encoder_layers"] = 2

@@ -128,3 +128,39 @@ def test_full_width_decode_step_fits_one_chip(topo):
     assert mem.argument_size_in_bytes >= param_bytes
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used / 2**30
+
+
+def test_moe_share_decode_step_fits_one_chip(topo):
+    """The accurate rung's decode step of one expert-parallel rank's share
+    of deepseek-moe-16b (14 of 28 layers, experts 0-7 of 64, batch 64,
+    prompt 128, 64 new tokens): the grouped dispatch's ``ragged_dot``
+    lowers to a TPU kernel, and the step, with the other rung's cache,
+    fits one v5e."""
+    import dataclasses
+
+    import repro.configs  # noqa: F401
+    from repro.launch.mesh import make_mesh
+    from repro.launch.serve import rung_configs, rung_programs
+    from repro.models.registry import build_model, get_config
+    from repro.sharding.planner import ShardingPlanner
+
+    cfg = dataclasses.replace(get_config("deepseek-moe-16b"), num_layers=14,
+                              experts_held=8)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    planner = ShardingPlanner(mesh, fsdp=False, context="serve")
+    rungs = rung_configs(cfg, 256)
+    with jax.set_mesh(mesh):
+        jitted, args = rung_programs(build_model(rungs["accurate"]), planner,
+                                     batch=64, prompt_len=128,
+                                     max_new=64)["decode"]
+        compiled = jitted.lower(*args).compile()
+        fast_state = rung_programs(build_model(rungs["fast"]), planner,
+                                   batch=64, prompt_len=128,
+                                   max_new=64)["decode"][1][1]
+    assert "ragged-dot" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    other = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(fast_state))
+    used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes + other)
+    assert used < 0.9 * V5E_HBM_BYTES, used / 2**30
