@@ -27,8 +27,9 @@ One chip, four phases:
   the bound is a mean correlation >= 0.85 over its steps (0.90 measured
   on a v5e), and it must sit closer to its window forward than to full
   attention (0.07 measured).  Last, the bf16 launcher's rungs against
-  the float32 forward at 1, 8 and 24 layers, a witness of the bf16 noise
-  floor; at one layer each rung's mean correlation must reach 0.9.
+  the float32 forward on the float32 masters of their bf16 weights at 1,
+  8 and 24 layers, a witness of the bf16 noise floor; at one layer each
+  rung's mean correlation must reach 0.9.
 - **validate**, **sweep_pipeline**, **replay_dag**: the jax Planner
   sweeps, each with ``backend="jax"`` against ``backend="numpy"`` on the
   same draws: ``Planner.validate`` on the RAG ladder of
@@ -196,25 +197,27 @@ def phase_serve() -> None:
     import numpy as np
 
     from repro.launch import serve
+    from repro.models.registry import build_model
 
     args, plane, res = serve_launcher()
     cfg = plane.cfg
     prompt = np.asarray(plane.prompt(args.batch, args.prompt_len))
     forwards = serve.reference_forwards(cfg, args.window)
-    with jax.default_matmul_precision("highest"):
-        witness = {cfg.num_layers: serve.rung_agreement(
-            forwards, plane.params, prompt, res)}
     del plane
     gc.collect()
 
     # the same launcher code at float32: each rung held to its own
     # attention pattern's teacher-forced forward over the prompt and every
     # decode input (the accurate rung's steps after the switch back
-    # included), on two rows of the batch
+    # included), on two rows of the batch.  Its parameters are the float32
+    # masters of the bf16 ones the launcher's plane holds, so the witness
+    # below reads the bf16 run against the forward on those masters.
     ref_plane, ref = reference_plane(cfg, args, jax.devices()[:1])
     check(ref.switches == res.switches, "float32 run switched differently")
     with jax.default_matmul_precision("highest"):
         agree = serve.rung_agreement(forwards, ref_plane.params, prompt, ref)
+        witness = {cfg.num_layers: serve.rung_agreement(
+            forwards, ref_plane.params, prompt, res)}
     del ref_plane
     gc.collect()
     report_agreement("float32", agree)
@@ -226,7 +229,7 @@ def phase_serve() -> None:
           "fast (window, int8 KV) decode disagrees with its window forward")
 
     # witness for the bf16 noise floor: the launcher's bf16 rungs against
-    # the float32 forward, by depth
+    # the float32 forward on the float32 masters of their weights, by depth
     for depth in WITNESS_DEPTHS:
         cut = dataclasses.replace(cfg, num_layers=depth)
         plane = serve.ServingPlane(
@@ -235,11 +238,15 @@ def phase_serve() -> None:
             prompt_len=args.prompt_len, max_new=args.tokens)
         res_d = plane.generate(plane.prompt(args.batch, args.prompt_len),
                                args.tokens)
+        del plane
+        gc.collect()
+        master = jax.jit(build_model(cut).init)(
+            jax.random.PRNGKey(serve.SEED))
         with jax.default_matmul_precision("highest"):
             witness[depth] = serve.rung_agreement(
-                serve.reference_forwards(cut, args.window), plane.params,
-                prompt, res_d)
-        del plane
+                serve.reference_forwards(cut, args.window), master, prompt,
+                res_d)
+        del master
         gc.collect()
     for depth in sorted(witness):
         report_agreement(f"bf16 {depth} layers",

@@ -71,7 +71,9 @@ def rung_schedule(n_tokens: int) -> List[str]:
 def rung_configs(base, window: int) -> Dict[str, object]:
     """accurate = the architecture as published; fast = sliding window
     plus int8 KV (attention-free archs keep two identical rungs so the
-    switching path still runs)."""
+    switching path still runs).  Both hold their parameters at
+    :func:`held_dtype`."""
+    base = dataclasses.replace(base, param_dtype=held_dtype(base).name)
     if base.family == "ssm":
         return {"accurate": base, "fast": base}
     return {
@@ -91,8 +93,8 @@ class ServeResult:
     rungs: List[str]                # rung that served each decode step
     spans: List[Span]               # the call's spans (see ``generate``)
     counters: Dict[str, int]        # host_bytes, catch_up_steps,
-                                    # handoff_overlapped; MoE models:
-                                    # moe_held_assignments
+                                    # handoff_overlapped, param_bytes;
+                                    # MoE models: moe_held_assignments
 
     def _first(self, name: str) -> Span:
         return next(s for s in self.spans if s.name == name)
@@ -141,6 +143,16 @@ def widen(x: np.ndarray, out: np.ndarray) -> None:
         np.copyto(out, x)
 
 
+def held_dtype(cfg):
+    """The dtype the serving plane holds floating-point parameters at: the
+    one its programs first round them to.  That is the compute dtype
+    (``cfg.dtype``) where it is narrower than ``cfg.param_dtype``, the
+    optimizer's store, which serving never updates; else ``param_dtype``.
+    The programs then compute with the same values and cast nothing."""
+    act, store = cfg.activation_dtype, cfg.parameter_dtype
+    return act if act.itemsize < store.itemsize else store
+
+
 def rung_programs(model, planner, *, batch: int, prompt_len: int,
                   max_new: int) -> Dict[str, tuple]:
     """The jitted prefill and decode step of one rung, with the planner's
@@ -183,10 +195,11 @@ class ServingPlane:
     """Two serving rungs over one set of weights on one mesh.
 
     Parameters are generated on the devices from ``SEED`` (nothing is
-    loaded) with the planner's shardings; each rung has a compiled prefill
-    and a compiled decode step (:func:`rung_programs`) whose cache
-    shardings come from the same planner.  ``compile_s`` holds the seconds
-    spent compiling each."""
+    loaded) with the planner's shardings, at the rungs' parameter dtype
+    (:func:`rung_configs`, :func:`held_dtype`); ``param_bytes`` is the
+    tree's size.  Each rung has a compiled prefill and a compiled decode
+    step (:func:`rung_programs`) whose cache shardings come from the same
+    planner.  ``compile_s`` holds the seconds spent compiling each."""
 
     def __init__(self, cfg, *, mesh, window: int, batch: int,
                  prompt_len: int, max_new: int):
@@ -223,6 +236,7 @@ class ServingPlane:
                     f"decode_{name}", *progs["decode"])
         self.params = init(jax.device_put(jax.random.PRNGKey(SEED),
                                           planner.replicated()))
+        self.param_bytes = sum(x.nbytes for x in jax.tree.leaves(self.params))
 
     def _compile(self, name, jitted, abstract_args):
         t0 = time.perf_counter()
@@ -272,12 +286,15 @@ class ServingPlane:
         widened while the device still had decode steps to run;
         ``moe_held_assignments`` (MoE): the (token, expert) assignments of
         every decode step of the call, catch-up steps included, that
-        landed on the experts this chip holds, summed over layers."""
+        landed on the experts this chip holds, summed over layers;
+        ``param_bytes``: the bytes of the parameter tree the plane holds,
+        every shard counted once."""
         import jax
         import jax.numpy as jnp
 
         rec = Recorder(next(self._calls))
         with rec.span("serve.call", call=rec.call):
+            rec.count("param_bytes", self.param_bytes)
             schedule = rung_schedule(n_tokens)
             p = tokens.shape[1]
             states, first = {}, None
@@ -501,8 +518,9 @@ def call_line(res: ServeResult) -> str:
     """One line per call, read from its spans: prefill, decode and
     hand-off times (the hand-off runs inside decode's interval), the
     hand-off's waits for the copies and its widening, how many steps were
-    widened while decode still ran, the bytes handed to the host, and for
-    a MoE model the assignments its held experts served."""
+    widened while decode still ran, the bytes handed to the host, the
+    bytes of the parameters held, and for a MoE model the assignments its
+    held experts served."""
     ms = {name: 1e3 * sum(s.seconds for s in res.spans if s.name == name)
           for name in ("serve.handoff", "serve.handoff.transfer",
                        "serve.handoff.widen")}
@@ -513,7 +531,8 @@ def call_line(res: ServeResult) -> str:
             f"{ms['serve.handoff.transfer']:.1f} ms, widening "
             f"{ms['serve.handoff.widen']:.1f} ms; "
             f"{res.counters['handoff_overlapped']} of {len(res.rungs)} "
-            f"steps while decode ran); {mb:.2f} MB to the host"
+            f"steps while decode ran); {mb:.2f} MB to the host; "
+            f"param_bytes {res.counters['param_bytes']}"
             + (f"; moe_held_assignments {res.counters['moe_held_assignments']}"
                if "moe_held_assignments" in res.counters else ""))
 

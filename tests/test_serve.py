@@ -225,6 +225,25 @@ def test_call_line_reads_the_spans(served):
             "while decode ran") in line
 
 
+def test_float32_plane_holds_float32(served):
+    """A plane that computes in float32 holds, and compiles for, float32
+    parameters."""
+    plane = served[2]
+    f32 = jax.numpy.dtype("float32")
+    assert {x.dtype for x in jax.tree.leaves(plane.params)} == {f32}
+    for name in serve.RUNGS:
+        for fn in (plane.prefill_fns[name], plane.step_fns[name]):
+            assert {a.dtype for a in jax.tree.leaves(fn.in_avals[0][0])} == {
+                f32}
+
+
+def test_param_bytes_counts_the_held_tree(served):
+    res, _, plane, _ = served
+    held = sum(x.nbytes for x in jax.tree.leaves(plane.params))
+    assert res.counters["param_bytes"] == held
+    assert f"MB to the host; param_bytes {held}" in serve.call_line(res)
+
+
 def test_another_length_compiles_nothing(served):
     """The hand-off is host work: a call of another length runs only the
     programs the first call built."""
